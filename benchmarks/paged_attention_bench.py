@@ -88,8 +88,8 @@ def run_cell(rng, batch, seq_len, ps, T):
     seq_lens[0] = seq_len
     table, P = fragmented_layout(rng, batch, seq_lens, ps)
     H = KV * G
-    kp = jnp.asarray(rng.normal(size=(P + 1, ps, KV, HD)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P + 1, ps, KV, HD)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P + 1, KV, ps, HD)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P + 1, KV, ps, HD)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(batch, T, H, HD)), jnp.float32)
     lens = np.asarray(seq_lens, np.int32)
     q_start = lens - T
@@ -100,13 +100,16 @@ def run_cell(rng, batch, seq_len, ps, T):
     dim = KV * HD
     dense_k = np.zeros((batch, smax, KV, HD), np.float32)
     dense_v = np.zeros((batch, smax, KV, HD), np.float32)
+    # paged_gather moves token-major (ps, dim) pages; the pool is head-major
+    kp_tok = kp.transpose(0, 2, 1, 3).reshape(P + 1, ps, dim)
+    vp_tok = vp.transpose(0, 2, 1, 3).reshape(P + 1, ps, dim)
     for b in range(batch):
         npg = int(-(-int(lens[b]) // ps))        # gather live pages only
         dense_k[b, :npg * ps] = np.asarray(
-            ops.paged_gather(kp.reshape(P + 1, ps, dim), table[b, :npg],
+            ops.paged_gather(kp_tok, table[b, :npg],
                              int(lens[b]))).reshape(npg * ps, KV, HD)
         dense_v[b, :npg * ps] = np.asarray(
-            ops.paged_gather(vp.reshape(P + 1, ps, dim), table[b, :npg],
+            ops.paged_gather(vp_tok, table[b, :npg],
                              int(lens[b]))).reshape(npg * ps, KV, HD)
     kpos = np.where(np.arange(smax)[None] < lens[:, None],
                     np.arange(smax)[None], -1)

@@ -58,6 +58,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 
 from repro.data.synthetic import ZipfMarkov  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.obs import (NULL_RECORDER, TraceRecorder,  # noqa: E402
                        write_metrics, write_trace)
@@ -581,6 +582,7 @@ def main() -> None:
                     "cell and exit 1 if tracing costs >10% wall or "
                     "changes the modeled schedule")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.hybrid and args.pair != "random":
         ap.error("--hybrid selects its own (jamba-shaped) pair; "

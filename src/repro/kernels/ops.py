@@ -1,7 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; on a
-real v5e slice set REPRO_PALLAS_INTERPRET=0 or pass interpret=False).
+``interpret`` defaults from the platform alone: compiled Mosaic kernels on
+TPU, the Pallas interpreter everywhere else (CPU tests).  Callers may pass
+``interpret`` explicitly; no environment variable changes the default.
 """
 from __future__ import annotations
 
@@ -18,10 +19,8 @@ from repro.kernels import ssm_scan as _ssm
 from repro.kernels import verify_accept as _va
 
 
-def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+def default_interpret() -> bool:
+    """Pallas interpret mode off-TPU, compiled Mosaic kernels on TPU."""
     return jax.default_backend() != "tpu"
 
 
@@ -29,7 +28,7 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal=True, window=0,
                     cap: Optional[float] = None, bq=128, bk=128,
                     interpret: Optional[bool] = None):
     """Prefill/decode attention.  See kernels.flash_attention."""
-    it = _default_interpret() if interpret is None else interpret
+    it = default_interpret() if interpret is None else interpret
     return _fa.flash_attention(q, k, v, q_pos, k_pos, causal=causal,
                                window=window, cap=cap, bq=bq, bk=bk,
                                interpret=it)
@@ -46,7 +45,7 @@ def branch_decode_attention(q, prefix_k, prefix_v, prefix_pos,
     Two flash passes (prefix broadcast via index_map, suffix per-branch)
     merged with the standard (m, l) combination.
     """
-    it = _default_interpret() if interpret is None else interpret
+    it = default_interpret() if interpret is None else interpret
     o1, m1, l1 = _fa.flash_attention(
         q, prefix_k, prefix_v, q_pos, prefix_pos, causal=True, cap=cap,
         out_stats=True, shared_kv=True, interpret=it)
@@ -72,14 +71,14 @@ def ssm_scan(x, dt, Bm, Cm, A, D, h0, *, bT=128, bE=256,
              interpret: Optional[bool] = None) -> Tuple[jax.Array, ...]:
     """Selective scan; ``return_states`` adds the per-step carries hs
     (B, T, E, N) — the SSM rollback checkpoints.  See kernels.ssm_scan."""
-    it = _default_interpret() if interpret is None else interpret
+    it = default_interpret() if interpret is None else interpret
     return _ssm.ssm_scan(x, dt, Bm, Cm, A, D, h0, bT=bT, bE=bE,
                          return_states=return_states, interpret=it)
 
 
 def verify_accept(p_logits, q_logits, tokens, uniforms, res_uniforms, *,
                   interpret: Optional[bool] = None):
-    it = _default_interpret() if interpret is None else interpret
+    it = default_interpret() if interpret is None else interpret
     return _va.verify_accept(p_logits, q_logits, tokens, uniforms,
                              res_uniforms, interpret=it)
 
@@ -101,7 +100,7 @@ def verify_accept_batched(p_logits, q_logits, tokens, lens, uniforms,
     if backend == "xla":
         return _va.verify_accept_batched_xla(p_logits, q_logits, tokens,
                                              lens, uniforms, res_uniforms)
-    it = _default_interpret() if interpret is None else interpret
+    it = default_interpret() if interpret is None else interpret
     return _va.verify_accept_batched(p_logits, q_logits, tokens, lens,
                                      uniforms, res_uniforms, interpret=it)
 
@@ -109,7 +108,7 @@ def verify_accept_batched(p_logits, q_logits, tokens, lens, uniforms,
 def paged_gather(pages, table, valid_len=None, *,
                  interpret: Optional[bool] = None):
     """Gather logical pages through a page table.  See kernels.paged."""
-    it = _default_interpret() if interpret is None else interpret
+    it = default_interpret() if interpret is None else interpret
     return _paged.paged_gather(jnp.asarray(pages), jnp.asarray(table),
                                valid_len, interpret=it)
 
@@ -134,7 +133,7 @@ def paged_attention(q, k_pages, v_pages, table, lens, q_start, *,
         return _pa.paged_decode_attention_xla(
             q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(lens),
             jnp.asarray(q_start), window=window, cap=cap)
-    it = _default_interpret() if interpret is None else interpret
+    it = default_interpret() if interpret is None else interpret
     return _pa.paged_decode_attention(
         q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(lens),
         jnp.asarray(q_start), window=window, cap=cap, interpret=it)

@@ -9,7 +9,11 @@ step DMAs one page HBM->VMEM->HBM with no host round-trip per page.
 
 The XLA alternative — ``buf[table]`` — materialises gather indices per
 element; the Pallas version moves whole (page_size, dim) tiles, which is the
-layout paged-attention kernels consume.  Grid = (n_logical_pages,).
+layout paged-attention kernels consume.  Grid = (n_logical_pages, dim
+blocks): a swap row of a full-width model is wide (16 layers x K/V x 4096
+= 131072 floats per token), and a whole (page_size, dim) f32 page, double
+buffered in and out, would overflow the TPU's 16 MiB of scoped VMEM, so
+the dim axis moves in lane-aligned blocks of at most ``_MAX_DIM_BLOCK``.
 
 ``valid_len`` masks the tail of a partially-filled last page to zero inside
 the kernel: the free list recycles pages without scrubbing them, so a
@@ -28,13 +32,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+_LANES = 128
+_MAX_DIM_BLOCK = 8192
+
+
+def _dim_block(dim: int) -> int:
+    """Largest lane-aligned block of at most _MAX_DIM_BLOCK that divides
+    ``dim``; a dim that is not lane-aligned moves whole."""
+    if dim % _LANES:
+        return dim
+    bd = min(dim, _MAX_DIM_BLOCK)
+    while dim % bd:
+        bd -= _LANES
+    return bd
+
+
 def _gather_kernel(table_ref, vlen_ref, pages_ref, out_ref, *, ps: int):
     # pages_ref is already the physical page selected by the index_map;
     # the body is a copy with the stale tail (rows >= valid_len) zeroed.
     del table_ref
     i = pl.program_id(0)
-    dim = out_ref.shape[-1]
-    row = i * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps, dim), 1)
+    row = i * ps + jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
     out_ref[...] = jnp.where(row < vlen_ref[0], pages_ref[...], 0)
 
 
@@ -55,11 +73,13 @@ def paged_gather(pages: jax.Array, table: jax.Array,
     if valid_len is None:
         valid_len = n * ps
     vlen = jnp.asarray(valid_len, jnp.int32).reshape(1)
+    bd = _dim_block(dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, ps, dim), lambda i, t, vl: (t[i], 0, 0))],
-        out_specs=pl.BlockSpec((1, ps, dim), lambda i, t, vl: (i, 0, 0)),
+        grid=(n, dim // bd),
+        in_specs=[pl.BlockSpec((1, ps, bd),
+                               lambda i, d, t, vl: (t[i], 0, d))],
+        out_specs=pl.BlockSpec((1, ps, bd), lambda i, d, t, vl: (i, 0, d)),
     )
     out = pl.pallas_call(
         functools.partial(_gather_kernel, ps=ps),

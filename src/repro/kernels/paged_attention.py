@@ -12,8 +12,12 @@ Layout and grid:
   * q:        (B, T, H, hd)  — T decode/verify tokens per row (T is small:
               pending + chunk, <= gamma + 2), pre-arranged to
               (B, KV, G, Tp, hd) with G = H // KV query groups;
-  * k_pages / v_pages: (P, page_size, KV, hd) physical paged buffers; the
-              last physical page is the serving layer's trash page and is
+  * k_pages / v_pages: (P, KV, page_size, hd) physical paged buffers,
+              head-major so that one head's page is a whole (page_size, hd)
+              tile — the TPU lowering requires a block's last two dims to
+              be (8, 128)-aligned or full, which a (page_size, 1, hd)
+              slice of a token-major page is not once KV > 1; the last
+              physical page is the serving layer's trash page and is
               never referenced by a live table entry;
   * table:    (B, n_max) int32 page table — entry j holds the physical page
               of logical page j; rows with fewer pages pad with the trash
@@ -25,8 +29,9 @@ Layout and grid:
 
 Grid = (B, KV, n_max) with the page axis innermost: the page table rides in
 SMEM via scalar prefetch and the k/v BlockSpec index_map sends grid step
-(b, h, j) to physical page ``table[b, j]``, so each step DMAs one
-(page_size, hd) tile per head straight from its scattered location.  The
+(b, h, j) to head ``h`` of physical page ``table[b, j]``, so each step
+DMAs one contiguous (page_size, hd) tile straight from its scattered
+location.  The
 (m, l, acc) online-softmax state lives in VMEM scratch across page steps;
 partial tail pages and pages beyond a row's count are masked by position
 (kpos >= lens[b]), exactly like the dense kernel masks unwritten cache
@@ -61,7 +66,7 @@ def _kernel(table_ref, lens_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, Tp, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (ps, hd)
     logits = jax.lax.dot_general(
         q, k, (((2,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)              # (G, Tp, ps)
@@ -85,7 +90,7 @@ def _kernel(table_ref, lens_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.where(mask[None], jnp.exp(logits - m_new[..., None]), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
+    v = v_ref[0, 0].astype(jnp.float32)                  # (ps, hd)
     pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     acc_scr[...] = acc_scr[...] * corr[..., None] + pv
@@ -115,16 +120,19 @@ def paged_decode_attention_xla(q: jax.Array, k_pages: jax.Array,
     be SPMD-partitioned).
     """
     B, T, H, hd = q.shape
-    _P, ps, KV, _ = k_pages.shape
+    _P, KV, ps, _ = k_pages.shape
     n_max = table.shape[1]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
     S = n_max * ps
 
-    # (B, n_max, ps, KV, hd) -> (B, S, KV, hd); logical key position of
+    # (B, n_max, KV, ps, hd) -> (B, S, KV, hd); logical key position of
     # table slot (j, o) is j*ps + o, matching the kernel's kpos iota.
-    k = k_pages[table].reshape(B, S, KV, hd).astype(jnp.float32)
-    v = v_pages[table].reshape(B, S, KV, hd).astype(jnp.float32)
+    def dense(pages):
+        g = pages[table].transpose(0, 1, 3, 2, 4)
+        return g.reshape(B, S, KV, hd).astype(jnp.float32)
+
+    k, v = dense(k_pages), dense(v_pages)
     qr = q.reshape(B, T, KV, G, hd).astype(jnp.float32) * scale
 
     logits = jnp.einsum("btkgh,bskh->bkgts", qr, k)      # (B, KV, G, T, S)
@@ -168,7 +176,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     Shapes as in the module docstring.  Returns (B, T, H, hd).
     """
     B, T, H, hd = q.shape
-    P, ps, KV, _ = k_pages.shape
+    P, KV, ps, _ = k_pages.shape
     n_max = table.shape[1]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
@@ -183,10 +191,10 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, G, Tp, hd),
                          lambda b, h, j, tbl, ln, qs: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, tbl, ln, qs: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, j, tbl, ln, qs: (tbl[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda b, h, j, tbl, ln, qs: (tbl[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda b, h, j, tbl, ln, qs: (tbl[b, j], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, G, Tp, hd), lambda b, h, j, tbl, ln, qs: (b, h, 0, 0, 0)),

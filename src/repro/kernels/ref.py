@@ -62,10 +62,10 @@ def paged_attention_ref(q, k_pages, v_pages, table, lens, q_start, *,
     dense (S, KV, hd) cache, mark slots beyond the row's length invalid
     (-1), and run naive attention.  Shapes as kernels.paged_attention."""
     B, T, _, _ = q.shape
-    _, ps, _, _ = k_pages.shape
+    _, KV, ps, hd = k_pages.shape
     S = table.shape[1] * ps
-    k = k_pages[table].reshape(B, S, *k_pages.shape[2:])
-    v = v_pages[table].reshape(B, S, *v_pages.shape[2:])
+    k = k_pages[table].transpose(0, 1, 3, 2, 4).reshape(B, S, KV, hd)
+    v = v_pages[table].transpose(0, 1, 3, 2, 4).reshape(B, S, KV, hd)
     kpos = jnp.arange(S, dtype=jnp.int32)[None]
     kpos = jnp.where(kpos < lens[:, None], kpos, -1)
     qpos = q_start[:, None] + jnp.arange(T, dtype=jnp.int32)[None]

@@ -17,8 +17,8 @@ Two modes (DESIGN.md §7):
     to dense rows or paged tables, so rollback stays O(1) and there is no
     sequential fallback for recurrent models.
 
-Speeds are reported on the modeled clock (runtime/cost_model.py — wall
-clock is meaningless on this CPU container); both modes print the same
+Speeds are reported on the modeled clock (runtime/cost_model.py; wall
+time is printed with the platform it ran on); both modes print the same
 ``aggregate tokens/s`` metric so they compare directly on an identical
 request set.  On real hardware the same engines run with draft/target
 sharded on disjoint mesh sub-axes (DESIGN.md §3).
@@ -34,9 +34,11 @@ import json
 import time
 
 import jax
+import numpy as np
 
 from repro.data.synthetic import ZipfMarkov
 from repro.launch import mesh as MESH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (NULL_RECORDER, TraceRecorder, profiler_session,
                        write_metrics, write_trace)
 from repro.runtime.cost_model import CostModel
@@ -48,7 +50,9 @@ from repro.runtime.scheduler import (Request, Scheduler,
 from repro.runtime.specbranch import SpecBranchEngine
 from repro.serving import (BatchedSpecBranchEngine, BatchedSpSEngine,
                            ContinuousBatchScheduler, ServeRequest)
-from repro.training.pairs import HYBRID_KINDS, VOCAB, get_pair, hybrid_pair
+from repro.training.pairs import (HYBRID_KINDS, LLAMA_KINDS, VOCAB,
+                                  get_pair, hybrid_pair, llama_configs,
+                                  llama_pair)
 
 ENGINES = {
     "autoregressive": AutoregressiveEngine,
@@ -66,11 +70,28 @@ BATCHED_ENGINES = {
 
 
 def load_pair(kind: str):
-    """Trained Zipf-Markov pairs, or random-init SSM-bearing pairs for the
-    hybrid serving path (falcon-shaped / jamba-shaped)."""
+    """Trained Zipf-Markov pairs, random-init SSM-bearing pairs for the
+    hybrid serving path (falcon-shaped / jamba-shaped), or the paper's
+    LLaMA-68M / LLaMA-7B pair at published widths (llama)."""
     if kind in HYBRID_KINDS:
         return hybrid_pair(kind)
+    if kind in LLAMA_KINDS:
+        return llama_pair()
     return get_pair(kind)
+
+
+def make_prompts(kind: str, n: int, length: int = 16, seed: int = 3
+                 ) -> list:
+    """``n`` prompts of ``length`` tokens for ``--pair kind``: the Zipf-
+    Markov language the toy pairs were trained on, or uniform ids over
+    the target vocabulary for the random-weight LLaMA pair."""
+    if kind in LLAMA_KINDS:
+        vocab = llama_configs()[1].vocab_size
+        rng = np.random.default_rng(seed)
+        return [list(map(int, p))
+                for p in rng.integers(0, vocab, (n, length))]
+    zm = ZipfMarkov(vocab=VOCAB, seed=7)
+    return [list(map(int, p)) for p in zm.prompts(n, length, seed=seed)]
 
 
 def build_engine(name: str, ecfg: EngineConfig, pair_kind: str = "misaligned",
@@ -121,7 +142,8 @@ def run_sequential(args, ecfg, prompts, rec=NULL_RECORDER) -> dict:
         agg["total_cost"] = clock
         agg["tokens_per_cost"] = agg["total_tokens"] / max(clock, 1e-9)
     print(f"\n== sequential {args.engine} on {args.pair} pair: "
-          f"{len(done)} requests, {wall:.1f}s wall (CPU) ==")
+          f"{len(done)} requests, {wall:.1f}s wall "
+          f"({jax.devices()[0].platform}) ==")
     for r in done:
         rep = r.result.report(cost)
         print(f"req {r.rid}: {rep['tokens']} tok  M={rep['M']:.2f} "
@@ -133,12 +155,14 @@ def run_sequential(args, ecfg, prompts, rec=NULL_RECORDER) -> dict:
     return agg
 
 
-def run_batched(args, ecfg, prompts, rec=NULL_RECORDER) -> dict:
+def build_batched_engine(args, ecfg, pair=None, rec=NULL_RECORDER):
+    """The batched engine ``--mode batched`` serves with.  ``pair`` is
+    ``load_pair(args.pair)``'s tuple when the caller already holds it."""
     if args.engine not in BATCHED_ENGINES:
         raise SystemExit(
             f"--mode batched supports {sorted(BATCHED_ENGINES)}; "
             f"run --engine {args.engine} with --mode sequential")
-    dp, dcfg, tp, tcfg = load_pair(args.pair)
+    dp, dcfg, tp, tcfg = pair if pair is not None else load_pair(args.pair)
     mesh = None
     if args.mesh:
         try:
@@ -158,18 +182,32 @@ def run_batched(args, ecfg, prompts, rec=NULL_RECORDER) -> dict:
         prefix_cache=(args.prefix_cache == "on"),
         mesh=mesh,
         draft_heads=load_draft_heads(args, ecfg))
-    eng.set_recorder(rec)        # before the scheduler grabs engine.rec
+    eng.set_recorder(rec)        # before a scheduler grabs engine.rec
+    return eng
+
+
+def serve_batched(eng, args, prompts, first_rid: int = 0):
+    """Serve ``prompts`` (request ids from ``first_rid``) on ``eng``.
+    Returns (results by rid, scheduler report, wall seconds); the clock
+    stops once the engine's device buffers are ready."""
     sched = ContinuousBatchScheduler(eng)
-    reqs = [ServeRequest(rid=i, prompt=p, max_new_tokens=args.new_tokens,
+    reqs = [ServeRequest(rid=first_rid + i, prompt=p,
+                         max_new_tokens=args.new_tokens,
                          arrival=i * args.arrival_interval)
             for i, p in enumerate(prompts)]
     t0 = time.time()
     results = sched.run(reqs)
+    jax.block_until_ready((eng.tgt_dec.cache, eng.dft_dec.cache))
     wall = time.time() - t0
-    rep = sched.report()
+    return results, sched.report(), wall
+
+
+def run_batched(args, ecfg, prompts, rec=NULL_RECORDER) -> dict:
+    eng = build_batched_engine(args, ecfg, rec=rec)
+    results, rep, wall = serve_batched(eng, args, prompts)
     print(f"\n== batched {args.engine} on {args.pair} pair: "
           f"{len(results)} requests, max_batch={args.max_batch}, "
-          f"{wall:.1f}s wall (CPU) ==")
+          f"{wall:.1f}s wall ({jax.devices()[0].platform}) ==")
     for rid in sorted(results):
         r = results[rid]
         print(f"req {rid}: {len(r.tokens)} tok  M={r.stats.mean_accepted:.2f}"
@@ -199,7 +237,9 @@ def run_batched(args, ecfg, prompts, rec=NULL_RECORDER) -> dict:
     return rep
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and cross-check the serving options (before any model
+    loads)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--engine", default="specbranch", choices=list(ENGINES))
     ap.add_argument("--mode", default=None,
@@ -207,11 +247,15 @@ def main() -> None:
                     help="default: batched for engines with a batched "
                     "implementation, sequential otherwise")
     ap.add_argument("--pair", default="misaligned",
-                    choices=["misaligned", "aligned", *HYBRID_KINDS],
+                    choices=["misaligned", "aligned", *HYBRID_KINDS,
+                             *LLAMA_KINDS],
                     help="misaligned/aligned: trained attention pairs; "
                     "falcon-shaped/jamba-shaped: random-init SSM/hybrid "
                     "pairs — batched mode serves them via the checkpoint-"
-                    "ring SSM cache, no sequential fallback")
+                    "ring SSM cache, no sequential fallback; llama: "
+                    "LLaMA-68M draft / LLaMA-7B target at published "
+                    "widths, random bf16 weights, target cut to 16 of 32 "
+                    "layers (one chip's share)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=48)
     ap.add_argument("--gamma", type=int, default=4)
@@ -294,7 +338,7 @@ def main() -> None:
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="also run a jax.profiler trace into DIR and "
                     "annotate dispatch ranges (TensorBoard/Perfetto)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.mode is None:
         args.mode = ("batched" if args.engine in BATCHED_ENGINES
                      else "sequential")
@@ -319,19 +363,26 @@ def main() -> None:
             MESH.validate_serving_mesh(*MESH.parse_mesh_arg(args.mesh))
         except ValueError as e:
             raise SystemExit(str(e))
+    return args
 
-    zm = ZipfMarkov(vocab=VOCAB, seed=7)
-    prompts = [list(map(int, p))
-               for p in zm.prompts(args.requests, 16, seed=3)]
+
+def engine_config(args, prompts) -> EngineConfig:
     max_len = args.max_len
     if max_len <= 0:
         need = (max(len(p) for p in prompts) + args.new_tokens
                 + 4 * (args.gamma + int(args.c)))
         max_len = max(512, 1 << (need - 1).bit_length())
-    ecfg = EngineConfig(gamma=args.gamma, c=args.c,
+    return EngineConfig(gamma=args.gamma, c=args.c,
                         temperature=args.temperature,
                         spec_predictor=args.spec_predictor,
                         draft_mode=args.draft_mode, max_len=max_len)
+
+
+def main() -> None:
+    args = parse_args()
+    enable_compile_cache()
+    prompts = make_prompts(args.pair, args.requests)
+    ecfg = engine_config(args, prompts)
     tracing = bool(args.trace or args.metrics_out or args.profile_dir)
     rec = TraceRecorder() if tracing else NULL_RECORDER
     if args.profile_dir:

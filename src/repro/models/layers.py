@@ -244,15 +244,17 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
             "a paged cache needs the (table, lens) view for this call"
         table, lens = paged
         table = table.astype(jnp.int32)
-        ps = cache["k_pages"].shape[1]
+        ps = cache["k_pages"].shape[2]
         trash = cache["k_pages"].shape[0] - 1
         lp = jnp.minimum(positions // ps, table.shape[1] - 1)
         page = jnp.take_along_axis(table, lp, axis=1)           # (B, T)
         page = jnp.where(positions < lens[:, None], page, trash)
         off = positions % ps
-        ck = cache["k_pages"].at[page, off].set(
+        # head-major pages (P, KV, ps, hd): the two advanced indices put
+        # (B, T) first, so the update is k's own (B, T, KV, hd) layout
+        ck = cache["k_pages"].at[page, :, off].set(
             k.astype(cache["k_pages"].dtype))
-        cv = cache["v_pages"].at[page, off].set(
+        cv = cache["v_pages"].at[page, :, off].set(
             v.astype(cache["v_pages"].dtype))
         out = _ops.paged_attention(q, ck, cv, table, lens, positions[:, 0],
                                    window=window, cap=cfg.attn_softcap,
@@ -326,14 +328,16 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, window: int,
 def init_paged_attn_cache(cfg: ModelConfig, num_pages: int, page_size: int
                           ) -> Params:
     """Physically paged KV storage for one attention slot: page id ->
-    (page_size, KV, hd) tile.  One extra trash page (index ``num_pages``)
+    head-major (KV, page_size, hd) tiles (the layout the paged kernel's
+    blocks need, kernels/paged_attention.py).  One extra trash page
+    (index ``num_pages``)
     absorbs masked pad writes.  No batch axis — rows are page-table views,
     validity is positional (pos < lens), so no "pos" leaf either."""
     KV, hd = cfg.num_kv_heads, cfg.hd
     dt = cfg.jdtype
     return {
-        "k_pages": jnp.zeros((num_pages + 1, page_size, KV, hd), dt),
-        "v_pages": jnp.zeros((num_pages + 1, page_size, KV, hd), dt),
+        "k_pages": jnp.zeros((num_pages + 1, KV, page_size, hd), dt),
+        "v_pages": jnp.zeros((num_pages + 1, KV, page_size, hd), dt),
     }
 
 

@@ -45,16 +45,16 @@ def _init_slot(key, cfg: ModelConfig, slot) -> Params:
 
 
 def init_params(key, cfg: ModelConfig) -> Params:
+    """Random weights from ``key``.  Each slot's layer stack is built by
+    one ``vmap`` over its layer keys, so under ``jit`` the stack is made
+    in place — no per-layer copies held beside the stacked result."""
     keys = jax.random.split(key, cfg.num_layers + 3)
     P, nper, nrem = cfg.period, cfg.n_periods, cfg.n_rem
-    # periodic stacks: one stacked pytree per slot
-    blocks = []
-    for s in range(P):
-        per_period = [_init_slot(keys[i * P + s], cfg, cfg.pattern[s])
-                      for i in range(nper)]
-        blocks.append(jax.tree.map(lambda *a: jnp.stack(a), *per_period)
-                      if nper > 1 else
-                      jax.tree.map(lambda a: a[None], per_period[0]))
+    # periodic stacks: one stacked pytree per slot; slot s of period i
+    # draws from keys[i * P + s]
+    blocks = [jax.vmap(lambda k, slot=cfg.pattern[s]:
+                       _init_slot(k, cfg, slot))(keys[s:nper * P:P])
+              for s in range(P)]
     rem = [_init_slot(keys[nper * P + r], cfg, cfg.pattern[r])
            for r in range(nrem)]
     dt = cfg.jdtype

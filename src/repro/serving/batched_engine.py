@@ -87,7 +87,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hrad as H
-from repro.kernels.ops import _default_interpret as _ops_default_interpret
+from repro.kernels.ops import default_interpret
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.obs.trace import NULL_RECORDER
@@ -679,7 +679,7 @@ class BatchedEngineBase:
         # fused verify route: the batched Pallas verify_accept kernel on
         # TPU (pre-scaled logits), the compiled XLA twin elsewhere
         self._use_kernel = DL.kernel_route(self._tt, self._dt)
-        self._kernel_interpret = _ops_default_interpret()
+        self._kernel_interpret = default_interpret()
         # uniform-window width one branch verify consumes per request:
         # [0, CH] chain block + [CH+1, CH+1+K] branch block
         self._W = self._CH + 1 + self._K + 1

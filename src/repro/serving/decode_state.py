@@ -135,7 +135,7 @@ class DenseAttnState:
 class PagedAttnState:
     """Attention KV scattered across a ``PagedKVPool``'s pages.
 
-    Leaves are ``(stack, num_pages + 1, page_size, ...)`` — no batch axis;
+    Leaves are ``(stack, num_pages + 1, KV, page_size, hd)`` — no batch axis;
     rows exist only as page-table views built per call from the pool
     (``bind`` attaches a pool stream to a decoder row).  Forks copy
     nothing (the pool's COW fork shares pages; a COW split is mirrored
@@ -188,7 +188,8 @@ class PagedAttnState:
                 continue
             for lf in jax.tree.leaves(c):
                 pg = lf[:, table]
-                # (stack, n, ps, KV, hd) -> token-major (n*ps, stack*KV*hd)
+                # (stack, n, KV, ps, hd) -> token-major (n*ps, stack*KV*hd)
+                pg = jnp.swapaxes(pg, 2, 3)
                 tok = jnp.moveaxis(
                     pg.reshape(pg.shape[0], -1, *pg.shape[3:]), 1, 0)
                 parts.append(tok[:length].reshape(length, -1)
@@ -209,7 +210,9 @@ class PagedAttnState:
         leaves, treedef = jax.tree.flatten(c)
         out = []
         for lf in leaves:
-            stack, tail = lf.shape[0], lf.shape[3:]
+            # head-major leaves (stack, P + 1, KV, ps, hd); rows are
+            # token-major (L, stack*KV*hd) as pack_parts wrote them
+            stack, tail = lf.shape[0], (lf.shape[2], lf.shape[4])
             width = stack * int(np.prod(tail, dtype=np.int64))
             seg = rows[:, off:off + width].reshape((L, stack) + tail)
             off += width
@@ -217,7 +220,8 @@ class PagedAttnState:
             if pad:
                 seg = np.concatenate(
                     [seg, np.zeros((pad, stack) + tail, seg.dtype)])
-            pages = np.moveaxis(seg.reshape((n, ps, stack) + tail), 2, 0)
+            pages = seg.reshape((n, ps, stack) + tail).transpose(2, 0, 3,
+                                                                 1, 4)
             out.append(lf.at[:, jnp.asarray(table)].set(
                 jnp.asarray(pages, lf.dtype)))
         return jax.tree.unflatten(treedef, out), off
@@ -361,12 +365,18 @@ class DecodeState:
         # swap layout: the attention half of a row flattens to (L,
         # swap_dim) float32 token rows (per token each leaf contributes
         # stack * trailing dims); recurrent rings ride snapshot/restore.
+        # paged leaves are head-major (stack, P + 1, KV, ps, hd): a token
+        # carries KV * hd of each; dense leaves carry their trailing dims
+        def tok_width(a):
+            tail = ((a.shape[2], a.shape[4]) if self.paged is not None
+                    else a.shape[3:])
+            return a.shape[0] * int(np.prod(tail, dtype=np.int64))
+
         self.swap_dim = 0
         for c in iter_slots(self.cache):
             if self.attn.owns(c):
-                self.swap_dim += sum(
-                    a.shape[0] * int(np.prod(a.shape[3:], dtype=np.int64))
-                    for a in jax.tree.leaves(c))
+                self.swap_dim += sum(tok_width(a)
+                                     for a in jax.tree.leaves(c))
         # token-packable attention + a recurrent half that can ride a ring
         # snapshot.  Dense hybrid stays UNswappable on purpose: the dense
         # backend is the reference oracle, and its preemption path (full

@@ -213,7 +213,7 @@ def serving_cache_specs(mesh: Mesh, cfg: ModelConfig, cache_shape: Any,
         dense backend's data parallelism), KV heads over "model"
         (head_dim when the KV count doesn't divide);
       * paged attention ``k_pages``/``v_pages`` (stack, num_pages + 1,
-        page_size, KV, hd): the page axis stays UNSHARDED — every device
+        KV, page_size, hd): the page axis stays UNSHARDED — every device
         holds the head-shard of every logical page, so a page id ``p``
         names the (device, p) pair family and the host page tables
         replicate verbatim.  KV heads (else head_dim) shard over "model";
@@ -226,22 +226,22 @@ def serving_cache_specs(mesh: Mesh, cfg: ModelConfig, cache_shape: Any,
     divides the dimension, so a 1x1 mesh (or an odd batch) shards nothing.
     """
 
-    def heads_spec(shape):
-        kv = _fit(mesh, shape[3], "model")
-        hd = None if kv else _fit(mesh, shape[4], "model")
+    def heads_spec(kv_dim, hd_dim):
+        kv = _fit(mesh, kv_dim, "model")
+        hd = None if kv else _fit(mesh, hd_dim, "model")
         return kv, hd
 
     def leaf(path, shape):
         name = path[-1]
         b = _fit(mesh, shape[1], batch_axis) if batch_axis else None
         if name in ("k", "v"):               # (stack, B, S, KV, hd)
-            kv, hd = heads_spec(shape)
+            kv, hd = heads_spec(shape[3], shape[4])
             return P(None, b, None, kv, hd)
         if name == "pos":                    # (stack, B, S)
             return P(None, b, None)
-        if name in ("k_pages", "v_pages"):   # (stack, P+1, ps, KV, hd)
-            kv, hd = heads_spec(shape)
-            return P(None, None, None, kv, hd)
+        if name in ("k_pages", "v_pages"):   # (stack, P+1, KV, ps, hd)
+            kv, hd = heads_spec(shape[2], shape[4])
+            return P(None, None, kv, None, hd)
         if name == "h_ring":                 # (stack, B, ring, E, N)
             return P(None, b, None, _fit(mesh, shape[3], "model"), None)
         if name == "conv_ring":              # (stack, B, ring, Cv-1, E)

@@ -43,8 +43,15 @@ def save(path: str, params: Any) -> None:
             os.remove(tmp)
 
 
+class StaleCheckpoint(ValueError):
+    """The file's arrays do not match the template: a key is missing or a
+    shape differs (the architecture changed under the same name)."""
+
+
 def load(path: str, template: Any) -> Any:
-    """Restore into the structure of ``template`` (dtypes preserved)."""
+    """Restore into the structure of ``template`` (dtypes preserved).
+    Raises FileNotFoundError for a missing file and StaleCheckpoint for
+    one whose keys or shapes do not match ``template``."""
     data = np.load(path)
     flat = {k: data[k] for k in data.files}
 
@@ -59,8 +66,12 @@ def load(path: str, template: Any) -> Any:
         if tree is None:
             return None
         key = prefix[:-1]
+        if key not in flat:
+            raise StaleCheckpoint(f"{path}: no array {key!r}")
         arr = flat[key]
-        assert arr.shape == tree.shape, (key, arr.shape, tree.shape)
+        if arr.shape != tree.shape:
+            raise StaleCheckpoint(f"{path}: {key!r} has shape {arr.shape}, "
+                                  f"expected {tree.shape}")
         return jnp.asarray(arr, dtype=tree.dtype)
 
     return rebuild(template)

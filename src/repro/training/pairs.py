@@ -12,12 +12,15 @@ Two pairs mirror the paper's regimes:
 """
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import os
+import sys
 from typing import Tuple
 
 import jax
 
+from repro.configs.paper_pairs import PAPER_PAIRS
 from repro.data.synthetic import ZipfMarkov
 from repro.models import model as M
 from repro.models.config import ModelConfig, dense_pattern
@@ -57,17 +60,30 @@ def _train(cfg: ModelConfig, steps: int, seed: int):
     return params, metrics
 
 
-def _get(cfg: ModelConfig, steps: int, seed: int):
+def _load_cached(path: str, template):
+    """Cached parameters, or None when the file is missing or was written
+    for another architecture (then the caller retrains and overwrites it).
+    Any other failure to read it propagates."""
+    try:
+        return ckpt.load(path, template)
+    except FileNotFoundError:
+        return None
+    except ckpt.StaleCheckpoint as e:
+        print(f"stale pair cache, retraining: {e}", file=sys.stderr)
+        return None
+
+
+def _get(cfg: ModelConfig, steps: int, seed: int, train: bool = True):
     path = os.path.join(CACHE_DIR, f"{cfg.name}.npz")
     template = jax.eval_shape(
         lambda: M.init_params(jax.random.PRNGKey(0), cfg))
     template = jax.tree.map(
         lambda s: jax.numpy.zeros(s.shape, s.dtype), template)
-    if os.path.exists(path):
-        try:
-            return ckpt.load(path, template)
-        except Exception:
-            pass
+    cached = _load_cached(path, template)
+    if cached is not None:
+        return cached
+    if not train:
+        raise FileNotFoundError(f"no usable trained pair file at {path}")
     params, _ = _train(cfg, steps, seed)
     ckpt.save(path, params)
     return params
@@ -99,11 +115,9 @@ def draft_heads_for(kind: str = "misaligned", K: int = 4,
         lambda s: jax.numpy.zeros(s.shape, s.dtype),
         jax.eval_shape(lambda: M.init_draft_heads(
             jax.random.PRNGKey(0), dcfg, K)))
-    if os.path.exists(path):
-        try:
-            return ckpt.load(path, template)
-        except Exception:
-            pass
+    cached = _load_cached(path, template)
+    if cached is not None:
+        return cached
     zm = ZipfMarkov(vocab=VOCAB, seed=7)
     data = zm.batch_iter(16, 64, seed=seed)
     tc = TrainConfig(steps=steps, batch=16, seq_len=64,
@@ -114,17 +128,20 @@ def draft_heads_for(kind: str = "misaligned", K: int = 4,
     return dhead
 
 
-def get_pair(kind: str = "misaligned", steps: int = 400
+def get_pair(kind: str = "misaligned", steps: int = 400, *,
+             train: bool = True
              ) -> Tuple[dict, ModelConfig, dict, ModelConfig]:
-    """Returns (draft_params, draft_cfg, target_params, target_cfg)."""
-    tgt = _get(TARGET_CFG, 400, seed=0)
+    """Returns (draft_params, draft_cfg, target_params, target_cfg).
+    ``train=False`` only loads the cached files (the misaligned pair's are
+    tracked in git) and raises FileNotFoundError where one is unusable."""
+    if kind not in ("misaligned", "aligned"):
+        raise ValueError(kind)
+    tgt = _get(TARGET_CFG, 400, seed=0, train=train)
     if kind == "misaligned":
-        dr = _get(DRAFT_MIS_CFG, MIS_STEPS, seed=6)
+        dr = _get(DRAFT_MIS_CFG, MIS_STEPS, seed=6, train=train)
         return dr, DRAFT_MIS_CFG, tgt, TARGET_CFG
-    if kind == "aligned":
-        dr = _get(DRAFT_ALI_CFG, ALI_STEPS, seed=6)
-        return dr, DRAFT_ALI_CFG, tgt, TARGET_CFG
-    raise ValueError(kind)
+    dr = _get(DRAFT_ALI_CFG, ALI_STEPS, seed=6, train=train)
+    return dr, DRAFT_ALI_CFG, tgt, TARGET_CFG
 
 
 HYBRID_KINDS = ("falcon-shaped", "jamba-shaped")
@@ -167,6 +184,38 @@ def hybrid_pair(kind: str, seed: int = 0
         raise ValueError(kind)
     tp = M.init_params(jax.random.PRNGKey(seed), tcfg)
     dp = M.init_params(jax.random.PRNGKey(seed + 1), dcfg)
+    return dp, dcfg, tp, tcfg
+
+
+LLAMA_KINDS = ("llama",)
+# LLaMA-7B's 32 layers hold 13.5 GB of bf16 weights — too much for one
+# 16 GB v5e beside the draft, the KV pools and activations.  One chip
+# serves the first 16 layers; the other 16 would sit on a second
+# pipeline stage.  Every width stays as published.
+LLAMA_TARGET_LAYERS = 16
+
+
+def llama_configs() -> Tuple[ModelConfig, ModelConfig]:
+    """(draft, target) configs of the paper's headline pair, LLaMA-68M /
+    LLaMA-7B (configs/paper_pairs.py), the target cut to
+    LLAMA_TARGET_LAYERS layers."""
+    draft, target, _c = PAPER_PAIRS["llama"]
+    target = dataclasses.replace(
+        target, name=f"{target.name}-{LLAMA_TARGET_LAYERS}l",
+        num_layers=LLAMA_TARGET_LAYERS)
+    return draft, target
+
+
+def llama_pair(seed: int = 0
+               ) -> Tuple[dict, ModelConfig, dict, ModelConfig]:
+    """The LLaMA-68M / LLaMA-7B pair at published widths (target depth
+    cut, see LLAMA_TARGET_LAYERS) with random bf16 weights from ``seed``.
+    Weights are built under ``jit`` so each layer stack is written in
+    place on the device (model.init_params)."""
+    dcfg, tcfg = llama_configs()
+    tp = jax.jit(lambda k: M.init_params(k, tcfg))(jax.random.PRNGKey(seed))
+    dp = jax.jit(lambda k: M.init_params(k, dcfg))(
+        jax.random.PRNGKey(seed + 1))
     return dp, dcfg, tp, tcfg
 
 

@@ -5,13 +5,6 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# The container has no `hypothesis`; fall back to the vendored shim (same
-# API surface, deterministic draws).  A real install takes precedence.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.append(os.path.join(os.path.dirname(__file__), "_vendor"))
-
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)

@@ -142,7 +142,8 @@ def test_verify_accept_residual_is_distribution():
     assert bool(sup[idx, np.asarray(res)].all())
 
 
-@pytest.mark.parametrize("n,ps,dim", [(1, 4, 8), (5, 8, 16), (3, 16, 24)])
+@pytest.mark.parametrize("n,ps,dim", [(1, 4, 8), (5, 8, 16), (3, 16, 24),
+                                      (2, 4, 3 * 8192)])  # 3 dim blocks
 def test_paged_gather_matches_numpy(n, ps, dim):
     """Paged gather through a scalar-prefetched page table == buf[table]."""
     rng = np.random.default_rng(7)

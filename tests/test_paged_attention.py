@@ -61,8 +61,8 @@ def test_paged_attention_vs_oracle(B, T, H, KV, hd, ps, variant):
     rng = np.random.default_rng(5)
     lens = [int(rng.integers(T + 1, 6 * ps)) for _ in range(B)]
     table, P = _layout(rng, lens, ps)
-    kp = jnp.asarray(rng.normal(size=(P + 1, ps, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P + 1, ps, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P + 1, KV, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P + 1, KV, ps, hd)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
     lens = np.asarray(lens, np.int32)
     q_start = lens - T
@@ -79,8 +79,8 @@ def test_paged_attention_vs_oracle(B, T, H, KV, hd, ps, variant):
 def test_paged_attention_zero_length_rows():
     """Unbound decoder rows attend over nothing: lens 0 must not NaN."""
     rng = np.random.default_rng(9)
-    kp = jnp.asarray(rng.normal(size=(4, 8, 2, 16)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(4, 8, 2, 16)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(4, 2, 8, 16)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(4, 2, 8, 16)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(2, 2, 4, 16)), jnp.float32)
     table = np.asarray([[0, 1], [3, 3]], np.int32)
     out = ops.paged_attention(q, kp, vp, table,
@@ -115,16 +115,18 @@ def test_paged_matches_dense_on_fragmented_tables(seed):
     fork = min(n_pages[0], n_pages[1])
     if fork > 1:
         table[1, :fork - 1] = table[0, :fork - 1]
-    kp = jnp.asarray(rng.normal(size=(P + 1, ps, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P + 1, ps, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P + 1, KV, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P + 1, KV, ps, hd)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, T, H, hd)), jnp.float32)
     lens = np.asarray(lens, np.int32)
     q_start = lens - T
     out = ops.paged_attention(q, kp, vp, table, lens, q_start)
 
     smax = table.shape[1] * ps
-    dense_k = np.asarray(kp)[table].reshape(B, smax, KV, hd)
-    dense_v = np.asarray(vp)[table].reshape(B, smax, KV, hd)
+    dense_k = np.asarray(kp)[table].transpose(0, 1, 3, 2, 4).reshape(
+        B, smax, KV, hd)
+    dense_v = np.asarray(vp)[table].transpose(0, 1, 3, 2, 4).reshape(
+        B, smax, KV, hd)
     kpos = np.where(np.arange(smax)[None] < lens[:, None],
                     np.arange(smax)[None], -1)
     qpos = q_start[:, None] + np.arange(T)[None]

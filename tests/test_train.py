@@ -6,6 +6,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.data.synthetic import ZipfMarkov
 from repro.models import model as M
@@ -50,6 +51,26 @@ def test_checkpoint_roundtrip():
         restored = ckpt.load(path, params)
         for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_pair_cache_retrains_only_missing_or_stale_files(tmp_path):
+    """The pair loaders fall back to training for a missing file or one
+    written for another architecture, and for nothing else."""
+    from repro.training.pairs import _load_cached
+    params = M.init_params(jax.random.PRNGKey(0), TINY)
+    assert _load_cached(str(tmp_path / "none.npz"), params) is None
+    other = M.init_params(jax.random.PRNGKey(0),
+                          ModelConfig(**{**TINY.__dict__, "d_model": 32}))
+    stale = str(tmp_path / "stale.npz")
+    ckpt.save(stale, other)
+    with pytest.raises(ckpt.StaleCheckpoint):
+        ckpt.load(stale, params)
+    assert _load_cached(stale, params) is None
+    broken = tmp_path / "broken.npz"
+    broken.write_bytes(b"not an npz")
+    with pytest.raises(ValueError) as err:      # unreadable: propagates
+        _load_cached(str(broken), params)
+    assert not isinstance(err.value, ckpt.StaleCheckpoint)
 
 
 def test_adamw_schedule():
