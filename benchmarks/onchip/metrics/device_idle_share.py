@@ -1,0 +1,8 @@
+"""Device layer: share of the traced window in which no operation ran on
+the chip, in percent."""
+
+
+def read(ctx):
+    if ctx.reduced is None:
+        return None
+    return 100.0 * ctx.reduced.idle_share()
