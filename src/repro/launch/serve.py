@@ -41,6 +41,7 @@ from repro.launch import mesh as MESH
 from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (NULL_RECORDER, TraceRecorder, profiler_session,
                        write_metrics, write_trace)
+from repro.obs.trace import set_trace_annotations
 from repro.runtime.cost_model import CostModel
 from repro.runtime.engines import (AdaEDLEngine, AutoregressiveEngine,
                                    EngineConfig, LookaheadEngine, PEARLEngine,
@@ -336,8 +337,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="dump the metrics registry (counters/gauges/"
                     "histograms); .json -> JSON, else plain text")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
-                    help="also run a jax.profiler trace into DIR and "
-                    "annotate dispatch ranges (TensorBoard/Perfetto)")
+                    help="also run a jax.profiler trace into DIR with the "
+                    "engine.* phase spans on (TensorBoard/Perfetto)")
     args = ap.parse_args(argv)
     if args.mode is None:
         args.mode = ("batched" if args.engine in BATCHED_ENGINES
@@ -386,8 +387,7 @@ def main() -> None:
     tracing = bool(args.trace or args.metrics_out or args.profile_dir)
     rec = TraceRecorder() if tracing else NULL_RECORDER
     if args.profile_dir:
-        from repro.serving import device_loop as DL
-        DL.set_trace_annotations(True)
+        set_trace_annotations(True)
     with profiler_session(args.profile_dir):
         if args.mode == "sequential":
             rep = run_sequential(args, ecfg, prompts, rec)

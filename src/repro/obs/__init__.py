@@ -1,6 +1,7 @@
 """Speculation-aware observability layer (DESIGN.md §7.9).
 
 trace.py    — structured event recorder (no-op NullRecorder when disabled)
+              and the engine's profiler spans (``annotate``)
 registry.py — counter/gauge/histogram metrics registry
 export.py   — Perfetto trace.json + metrics dumps + jax.profiler hooks
 """

@@ -34,15 +34,53 @@ Speculation-event causes (rollback attribution):
                       continuation depth rolls back
   ``branch-adopt``  — a branch won; losses are pruned_tokens (H-RAD
                       posterior), not rollback
+
+Profiler spans (``annotate``) are the second, independent channel: named
+``jax.profiler.TraceAnnotation`` ranges around the serving engine's
+phases, written into the profiler's own trace and so on the same clock
+as the device's operations.  The set (DESIGN.md §7.9): ``engine.round``
+around every ``step_round`` body, and beneath it ``engine.make_room``,
+``engine.branch_fork``, ``engine.verify_dispatch``, ``engine.draft_tick``,
+``engine.draft_resolve`` and ``engine.commit``; ``engine.fetch`` around
+every device -> host sync (``batched_engine._count_fetch``, so the count
+of these spans is the engine's ``host_fetches`` tally), and
+``engine.decoder_step``, ``engine.admit`` and ``engine.retire`` around
+each decoder forward's host side, ``commit_admissions`` and
+``retire_done``.  Off unless ``set_trace_annotations(True)``; off, a span
+is one shared ``nullcontext`` and no name or field is built.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from repro.obs.registry import MetricsRegistry
 
-__all__ = ["TraceRecorder", "NullRecorder", "NULL_RECORDER"]
+__all__ = ["TraceRecorder", "NullRecorder", "NULL_RECORDER",
+           "set_trace_annotations", "annotate"]
+
+_ANNOTATE = False
+_OFF = contextlib.nullcontext()
+
+
+def set_trace_annotations(on: bool) -> None:
+    """Turn the ``engine.*`` profiler spans on or off (process-wide).
+    ``launch/serve.py --profile-dir`` and the on-chip benchmark's traced
+    run turn them on around their profiler sessions."""
+    global _ANNOTATE
+    _ANNOTATE = bool(on)
+
+
+def annotate(name: str):
+    """The program's span primitive: a ``jax.profiler.TraceAnnotation``
+    named ``name`` when spans are on, a shared no-op context otherwise.
+    Callers pass a literal name, so the off path builds nothing."""
+    if _ANNOTATE:
+        return jax.profiler.TraceAnnotation(name)
+    return _OFF
 
 
 class NullRecorder:
@@ -192,8 +230,6 @@ class TraceRecorder(NullRecorder):
             reg.counter(f"hrad_signal_{hrad}_total").inc()
         if pred is not None:
             reg.counter("pred_decisions_total").inc()
-            reg.histogram("pred_score").observe(float(pred["score"]))
-            reg.histogram("pred_gamma").observe(float(pred["gamma"]))
         if stage in ("sps", "branch") and gamma > 0:
             rate = min(accepted, gamma) / gamma
             reg.histogram("acceptance_rate").observe(rate)
@@ -213,7 +249,6 @@ class TraceRecorder(NullRecorder):
                    batch=batch, dispatches=dispatches,
                    wall0=wall0, wall1=wall1, t0=t0, t1=t1)
         self.registry.counter("rounds_total").inc()
-        self.registry.histogram("round_wall_s").observe(wall1 - wall0)
         if dispatches is not None:
             # per-round device-dispatch count (DESIGN.md §7.12): the
             # single-pass parallel drafting claim — 1 + gamma collapsing

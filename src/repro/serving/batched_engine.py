@@ -90,7 +90,7 @@ from repro.core import hrad as H
 from repro.kernels.ops import default_interpret
 from repro.models import model as M
 from repro.models.config import ModelConfig
-from repro.obs.trace import NULL_RECORDER
+from repro.obs.trace import NULL_RECORDER, annotate
 from repro.runtime import predictor as PRED
 from repro.runtime import sampling as S
 from repro.runtime.cost_model import CostModel
@@ -106,11 +106,26 @@ def _count_fetch(owner, arr) -> np.ndarray:
     """THE device -> host gate of the serving layer: every byte that
     crosses the boundary goes through here and lands in ``owner``'s
     ``xfer_bytes``/``xfer_fetches`` tally (decoder or engine) — the
-    counters the metrics report and the CI transfer baseline compare."""
-    a = np.asarray(jax.device_get(arr))
+    counters the metrics report and the CI transfer baseline compare.
+    Each call is one ``engine.fetch`` profiler span: the host blocked on
+    the device."""
+    with annotate("engine.fetch"):
+        a = np.asarray(jax.device_get(arr))
     owner.xfer_bytes += a.nbytes
     owner.xfer_fetches += 1
     return a
+
+
+def _spanned(name: str):
+    """Run the decorated method inside the profiler span ``name``
+    (``obs/trace.py::annotate``: free while the spans are off)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kw):
+            with annotate(name):
+                return fn(*args, **kw)
+        return spanned
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -361,6 +376,7 @@ class BatchedDecoder:
         self.state.copy_page(src, dst)
 
     # -------------------------------------------------------------- compute
+    @_spanned("engine.decoder_step")
     def step(self, tokens, pos) -> Tuple[jax.Array, jax.Array]:
         """Batched forward: tokens (n_rows, T) int32 (numpy OR device —
         the device-resident loop chains sampled tokens straight back in),
@@ -381,6 +397,7 @@ class BatchedDecoder:
         self.n_call_tokens += int(np.prod(tokens.shape))
         return logits, feats
 
+    @_spanned("engine.decoder_step")
     def step_draft(self, tokens, pos, nreal, mask_embed
                    ) -> Tuple[jax.Array, jax.Array]:
         """Parallel-draft forward (DESIGN.md §7.12): per row, ``nreal[b]``
@@ -410,6 +427,7 @@ class BatchedDecoder:
         self.n_call_tokens += int(np.prod(tokens.shape))
         return logits, feats
 
+    @_spanned("engine.decoder_step")
     def prefill_rows(self, parts: Sequence[Tuple[int, Sequence[int]]]
                      ) -> Tuple[jax.Array, jax.Array]:
         """Batched bucketed prefill: ingest each ``(row, tokens)`` prompt
@@ -465,6 +483,7 @@ class BatchedDecoder:
         lane 0 of the returned device (logits, feats) is the row's."""
         return self.prefill_rows([(row, list(tokens))])
 
+    @_spanned("engine.decoder_step")
     def prefill_rows_at(self, parts: Sequence[Tuple[int, Sequence[int]]],
                         starts: Sequence[int]
                         ) -> Tuple[jax.Array, jax.Array]:
@@ -1067,6 +1086,7 @@ class BatchedEngineBase:
                 self.rec.request("swap_in", rid, t=self.clock)
         return seq
 
+    @_spanned("engine.admit")
     def commit_admissions(self) -> None:
         """Run the deferred prefills of the current admission group: group
         prompts onto the prefill length ladder and ingest each rung with
@@ -1331,6 +1351,7 @@ class BatchedEngineBase:
                             created=created, t=self.clock)
 
     # -------------------------------------------------------------- retire
+    @_spanned("engine.retire")
     def retire_done(self) -> List[Tuple[_Seq, GenResult]]:
         out = []
         for seq in [s for s in self.active if s.done]:
@@ -1402,6 +1423,7 @@ class BatchedSpSEngine(BatchedEngineBase):
     the (S, 3 + gamma) verdict packet."""
     name = "batched-sps"
 
+    @_spanned("engine.round")
     def step_round(self) -> Dict[str, Any]:
         if self.ecfg.draft_mode == "parallel":
             # the sequential body below stays byte-identical (tests pin it
@@ -1435,7 +1457,8 @@ class BatchedSpSEngine(BatchedEngineBase):
                         [(("t", s.rid), len(s.tgt.pending) + g_of[s.rid])
                          for s in ss]))
 
-        preempted = self._make_room(seqs, fits)
+        with annotate("engine.make_room"):
+            preempted = self._make_room(seqs, fits)
         if not seqs:
             return {"committed": {}, "preempted": preempted}
         n_d = self.dft_dec.n_rows
@@ -1443,83 +1466,85 @@ class BatchedSpSEngine(BatchedEngineBase):
 
         # ---- draft stage: batched pending ingest + gamma sampling ticks,
         # sampled ids chained on device tick to tick
-        lg, _ = self._ingest(
-            self.dft_dec,
-            [(s.dft, ("d", s.rid), list(s.dft.pending)) for s in seqs])
-        # pending lengths differ (1 after a reject, 2 after an all-accept):
-        # read each row's logits at its REAL last token, not the pad
-        last = np.zeros(n_d, np.int32)
-        for s in seqs:
-            last[s.dft.row] = len(s.dft.pending) - 1
-            s.dft.pending = []
+        with annotate("engine.draft_tick"):
+            lg, _ = self._ingest(
+                self.dft_dec,
+                [(s.dft, ("d", s.rid), list(s.dft.pending)) for s in seqs])
+            # pending lengths differ (1 after a reject, 2 after an all-accept):
+            # read each row's logits at its REAL last token, not the pad
+            last = np.zeros(n_d, np.int32)
+            for s in seqs:
+                last[s.dft.row] = len(s.dft.pending) - 1
+                s.dft.pending = []
         tok_ticks, q_ticks = [], []
         for i in range(g):
-            # rows whose own g_i is exhausted park (rid/ctr 0 — their lane
-            # computes garbage that glens masks out of the verify)
-            ticking = [s for s in seqs if g_of[s.rid] > i]
-            rids, ctrs = self._by_row(self.dft_dec, ticking,
-                                      lambda s: s.dft.row)
-            toks, qsl, _ = DL.tick_sample(lg, jnp.asarray(last),
-                                          jnp.asarray(rids),
-                                          jnp.asarray(ctrs), self._key,
-                                          dtemp=self._dt, stemp=self._st,
-                                          mesh=self.mesh)
-            tok_ticks.append(toks)
-            q_ticks.append(qsl)
-            for s in ticking:
-                s.ctr += 1
-                s.stats.draft_tokens += 1
-            if i < g - 1:
-                pairs = [(s.dft, ("d", s.rid)) for s in ticking
-                         if g_of[s.rid] > i + 1]
-                if pairs:
-                    lg, _ = self._ingest_dev(self.dft_dec, pairs, toks)
-                    last[:] = 0
+            with annotate("engine.draft_tick"):
+                # rows whose own g_i is exhausted park (rid/ctr 0 — their
+                # lane computes garbage that glens masks out of the verify)
+                ticking = [s for s in seqs if g_of[s.rid] > i]
+                rids, ctrs = self._by_row(self.dft_dec, ticking,
+                                          lambda s: s.dft.row)
+                toks, qsl, _ = DL.tick_sample(lg, jnp.asarray(last),
+                                              jnp.asarray(rids),
+                                              jnp.asarray(ctrs), self._key,
+                                              dtemp=self._dt, stemp=self._st,
+                                              mesh=self.mesh)
+                tok_ticks.append(toks)
+                q_ticks.append(qsl)
+                for s in ticking:
+                    s.ctr += 1
+                    s.stats.draft_tokens += 1
+                if i < g - 1:
+                    pairs = [(s.dft, ("d", s.rid)) for s in ticking
+                             if g_of[s.rid] > i + 1]
+                    if pairs:
+                        lg, _ = self._ingest_dev(self.dft_dec, pairs, toks)
+                        last[:] = 0
         tok_stack = jnp.stack(tok_ticks)          # (g, n_d) device
         q_stack = jnp.stack(q_ticks)              # (g, n_d, V) device
         wall_draft = rec.now()
 
         # ---- verify stage: ONE batched target call + fused device verdict
-        pends = {s.rid: list(s.tgt.pending) for s in seqs}
-        npend = np.zeros(B, np.int32)
-        pend_arr = np.zeros((B, 2), np.int32)
-        trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB = pad lane
-        drows = np.zeros(B, np.int32)
-        rid_l = np.zeros(B, np.int32)
-        ctr_l = np.zeros(B, np.int32)
-        glens = np.zeros(B, np.int32)      # pad lanes: 0 (garbage, unread)
-        for i, s in enumerate(seqs):
-            p = pends[s.rid]
-            npend[i] = len(p)
-            pend_arr[i, :len(p)] = p
-            trows[i] = s.tgt.row
-            drows[i] = s.dft.row
-            rid_l[i] = s.rid
-            ctr_l[i] = s.ctr
-            glens[i] = g_of[s.rid]
-        Tb = DL.bucket(int((npend + glens).max()) if pred is not None
-                       else int(npend.max()) + g)
-        toks_full = DL.compose_verify_tokens(
-            jnp.asarray(pend_arr), jnp.asarray(npend), tok_stack,
-            jnp.asarray(drows), jnp.asarray(trows),
-            n_rows=self.tgt_dec.n_rows, Tb=Tb)
-        # staging mirrors _ingest/_batched for a device-composed token
-        # frame: pool-extend by the REAL count, overflow-check the PADDED
-        # width (same `p0 + T` rule _batched applies)
-        pos = np.minimum(self.tgt_dec.row_pos,
-                         self.tgt_dec.max_len - Tb).astype(np.int32)
-        for s in seqs:
-            self.pools["t"].extend(("t", s.rid),
-                                   len(pends[s.rid]) + g_of[s.rid])
-            if s.tgt.ing + Tb > self.tgt_dec.max_len:
-                raise RuntimeError(
-                    f"row {s.tgt.row} overflows max_len")
-            pos[s.tgt.row] = s.tgt.ing
-        tlg, feats = self.tgt_dec.step(toks_full, pos)
-        for s in seqs:
-            s.tgt.ing += len(pends[s.rid]) + g_of[s.rid]
-            self.tgt_dec.row_pos[s.tgt.row] = s.tgt.ing
-        with DL.annotate("sps_verify"):
+        with annotate("engine.verify_dispatch"):
+            pends = {s.rid: list(s.tgt.pending) for s in seqs}
+            npend = np.zeros(B, np.int32)
+            pend_arr = np.zeros((B, 2), np.int32)
+            trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB = pad lane
+            drows = np.zeros(B, np.int32)
+            rid_l = np.zeros(B, np.int32)
+            ctr_l = np.zeros(B, np.int32)
+            glens = np.zeros(B, np.int32)      # pad lanes: 0 (garbage, unread)
+            for i, s in enumerate(seqs):
+                p = pends[s.rid]
+                npend[i] = len(p)
+                pend_arr[i, :len(p)] = p
+                trows[i] = s.tgt.row
+                drows[i] = s.dft.row
+                rid_l[i] = s.rid
+                ctr_l[i] = s.ctr
+                glens[i] = g_of[s.rid]
+            Tb = DL.bucket(int((npend + glens).max()) if pred is not None
+                           else int(npend.max()) + g)
+            toks_full = DL.compose_verify_tokens(
+                jnp.asarray(pend_arr), jnp.asarray(npend), tok_stack,
+                jnp.asarray(drows), jnp.asarray(trows),
+                n_rows=self.tgt_dec.n_rows, Tb=Tb)
+            # staging mirrors _ingest/_batched for a device-composed token
+            # frame: pool-extend by the REAL count, overflow-check the PADDED
+            # width (same `p0 + T` rule _batched applies)
+            pos = np.minimum(self.tgt_dec.row_pos,
+                             self.tgt_dec.max_len - Tb).astype(np.int32)
+            for s in seqs:
+                self.pools["t"].extend(("t", s.rid),
+                                       len(pends[s.rid]) + g_of[s.rid])
+                if s.tgt.ing + Tb > self.tgt_dec.max_len:
+                    raise RuntimeError(
+                        f"row {s.tgt.row} overflows max_len")
+                pos[s.tgt.row] = s.tgt.ing
+            tlg, feats = self.tgt_dec.step(toks_full, pos)
+            for s in seqs:
+                s.tgt.ing += len(pends[s.rid]) + g_of[s.rid]
+                self.tgt_dec.row_pos[s.tgt.row] = s.tgt.ing
             packet_dev = DL.sps_verify(
                 tlg, q_stack, tok_stack, jnp.asarray(trows),
                 jnp.asarray(drows), jnp.asarray(npend), jnp.asarray(rid_l),
@@ -1528,60 +1553,61 @@ class BatchedSpSEngine(BatchedEngineBase):
                 g=g, ttemp=self._tt,
                 dtemp=self._dt, kernel=self._use_kernel,
                 interpret=self._kernel_interpret, mesh=self.mesh)
-        for s in seqs:
-            s.ctr += g_of[s.rid] + 1
+            for s in seqs:
+                s.ctr += g_of[s.rid] + 1
         pk = self._fetch(packet_dev)       # the round's ONLY host fetch
         wall_verify = rec.now()
         now = self.clock + self.cost.round_cost(("serial", g, 1))
-        committed: Dict[int, int] = {}
-        for i, s in enumerate(seqs):
-            g_i = g_of[s.rid]
-            n, nxt, all_acc = int(pk[i, 0]), int(pk[i, 1]), bool(pk[i, 2])
-            dr = [int(x) for x in pk[i, 3:3 + g_i]]
-            npend_i = len(pends[s.rid])
-            before = min(len(s.out), s.max_new)
-            s.stats.target_calls += 1
-            s.feats_last = feats[:, s.tgt.row:s.tgt.row + 1,
-                                 npend_i + g_i - 1, :]
-            s.tgt.pending = []
-            if pred is not None:
-                # update from the packet already on host: no extra syncs
-                pred.update(s.rid, all_acc, n / max(g_i, 1))
-            if all_acc:
-                self._commit(s, dr + [nxt], now)
-                s.stats.run_extend(g_i + 1)
-                s.tgt.pending = [nxt]
-                s.dft.pending = [dr[-1], nxt]
-                if rec.enabled:
-                    rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
-                             committed=g_i + 1, accepted=g_i, drafted=g_i,
-                             cause="accept", gamma=g_i, bonus=True,
-                             pred=(s.pdec.obs() if s.pdec is not None
-                                   else None), t=now)
-            else:
-                self._commit(s, dr[:n] + [nxt], now)
-                s.stats.run_extend(n)
-                s.stats.run_break()
-                s.stats.rollback_tokens += g_i - n
-                self._rollback_streams(s)
-                if rec.enabled:
-                    rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
-                             committed=n + 1, accepted=n, drafted=g_i,
-                             rolled_back=g_i - n, cause="chunk-reject",
-                             gamma=g_i,
-                             pred=(s.pdec.obs() if s.pdec is not None
-                                   else None), t=now)
-            committed[s.rid] = min(len(s.out), s.max_new) - before
-        if rec.enabled:
-            wall1 = rec.now()
-            rec.span("draft", wall0, wall_draft, engine=self.name)
-            rec.span("verify", wall_draft, wall_verify, engine=self.name,
-                     batch=len(seqs))
-            rec.span("commit", wall_verify, wall1, engine=self.name)
-            rec.round(engine=self.name, index=rnd_idx, mode="serial",
-                      draft_steps=g, target_calls=1, batch=len(seqs),
-                      wall0=wall0, wall1=wall1, t0=self.clock, t1=now)
-        self._finish_round("serial", g, 1)
+        with annotate("engine.commit"):
+            committed: Dict[int, int] = {}
+            for i, s in enumerate(seqs):
+                g_i = g_of[s.rid]
+                n, nxt, all_acc = int(pk[i, 0]), int(pk[i, 1]), bool(pk[i, 2])
+                dr = [int(x) for x in pk[i, 3:3 + g_i]]
+                npend_i = len(pends[s.rid])
+                before = min(len(s.out), s.max_new)
+                s.stats.target_calls += 1
+                s.feats_last = feats[:, s.tgt.row:s.tgt.row + 1,
+                                     npend_i + g_i - 1, :]
+                s.tgt.pending = []
+                if pred is not None:
+                    # update from the packet already on host: no extra syncs
+                    pred.update(s.rid, all_acc, n / max(g_i, 1))
+                if all_acc:
+                    self._commit(s, dr + [nxt], now)
+                    s.stats.run_extend(g_i + 1)
+                    s.tgt.pending = [nxt]
+                    s.dft.pending = [dr[-1], nxt]
+                    if rec.enabled:
+                        rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
+                                 committed=g_i + 1, accepted=g_i, drafted=g_i,
+                                 cause="accept", gamma=g_i, bonus=True,
+                                 pred=(s.pdec.obs() if s.pdec is not None
+                                       else None), t=now)
+                else:
+                    self._commit(s, dr[:n] + [nxt], now)
+                    s.stats.run_extend(n)
+                    s.stats.run_break()
+                    s.stats.rollback_tokens += g_i - n
+                    self._rollback_streams(s)
+                    if rec.enabled:
+                        rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
+                                 committed=n + 1, accepted=n, drafted=g_i,
+                                 rolled_back=g_i - n, cause="chunk-reject",
+                                 gamma=g_i,
+                                 pred=(s.pdec.obs() if s.pdec is not None
+                                       else None), t=now)
+                committed[s.rid] = min(len(s.out), s.max_new) - before
+            if rec.enabled:
+                wall1 = rec.now()
+                rec.span("draft", wall0, wall_draft, engine=self.name)
+                rec.span("verify", wall_draft, wall_verify, engine=self.name,
+                         batch=len(seqs))
+                rec.span("commit", wall_verify, wall1, engine=self.name)
+                rec.round(engine=self.name, index=rnd_idx, mode="serial",
+                          draft_steps=g, target_calls=1, batch=len(seqs),
+                          wall0=wall0, wall1=wall1, t0=self.clock, t1=now)
+            self._finish_round("serial", g, 1)
         return {"committed": committed, "preempted": preempted}
 
     def _step_round_parallel(self) -> Dict[str, Any]:
@@ -1622,7 +1648,8 @@ class BatchedSpSEngine(BatchedEngineBase):
                         [(("t", s.rid), len(s.tgt.pending) + g_of[s.rid])
                          for s in ss]))
 
-        preempted = self._make_room(seqs, fits)
+        with annotate("engine.make_room"):
+            preempted = self._make_room(seqs, fits)
         if not seqs:
             return {"committed": {}, "preempted": preempted}
         n_d = self.dft_dec.n_rows
@@ -1631,85 +1658,86 @@ class BatchedSpSEngine(BatchedEngineBase):
 
         # ---- draft stage: ONE forward (pending ++ g slots per row), then
         # one fused chunk-sampling dispatch off its logits/features
-        P = {s.rid: len(s.dft.pending) for s in seqs}
-        T = DL.bucket(max(P.values()) + g)
-        toks = np.zeros((n_d, T), np.int32)
-        nreal = np.zeros(n_d, np.int32)
-        last = np.zeros(n_d, np.int32)
-        pos = np.minimum(self.dft_dec.row_pos,
-                         self.dft_dec.max_len - T).astype(np.int32)
-        for s in seqs:
-            p_i = P[s.rid]
-            self.pools["d"].extend(("d", s.rid), p_i)
-            if s.dft.ing + T > self.dft_dec.max_len:
-                raise RuntimeError(
-                    f"row {s.dft.row} overflows max_len")
-            toks[s.dft.row, :p_i] = s.dft.pending
-            nreal[s.dft.row] = p_i
-            last[s.dft.row] = p_i - 1
-            pos[s.dft.row] = s.dft.ing
-            s.dft.pending = []
-        lg, dfeats = self.dft_dec.step_draft(
-            toks, pos, nreal, self.draft_heads["mask_embed"])
-        for s in seqs:
-            s.dft.ing += P[s.rid]
-            self.dft_dec.row_pos[s.dft.row] = s.dft.ing
-        rids, ctrs = self._by_row(self.dft_dec, seqs, lambda s: s.dft.row)
-        tok_stack, q_full, _ = DL.draft_chunk(
-            lg, dfeats, self.dp["final_norm"], self.draft_heads["heads"],
-            jnp.asarray(last), jnp.asarray(rids), jnp.asarray(ctrs),
-            self._key, g=g, dtemp=self._dt, stemp=self._st,
-            eps=self.dcfg.norm_eps, cap=self.dcfg.final_softcap,
-            mesh=self.mesh)
-        q_stack = q_full[:g]
-        # PRNG parity: token i was drawn at (rid, ctr0 + i) — the exact
-        # coordinates the sequential ticks consume.  Rows with g_i < g
-        # sampled garbage at ctr0+g_i..ctr0+g-1; those draws are discarded
-        # (glens masks them out of the verify), so the coordinate overlap
-        # with the verify window below is harmless.
-        for s in seqs:
-            s.ctr += g_of[s.rid]
-            s.stats.draft_tokens += g_of[s.rid]
+        with annotate("engine.draft_tick"):
+            P = {s.rid: len(s.dft.pending) for s in seqs}
+            T = DL.bucket(max(P.values()) + g)
+            toks = np.zeros((n_d, T), np.int32)
+            nreal = np.zeros(n_d, np.int32)
+            last = np.zeros(n_d, np.int32)
+            pos = np.minimum(self.dft_dec.row_pos,
+                             self.dft_dec.max_len - T).astype(np.int32)
+            for s in seqs:
+                p_i = P[s.rid]
+                self.pools["d"].extend(("d", s.rid), p_i)
+                if s.dft.ing + T > self.dft_dec.max_len:
+                    raise RuntimeError(
+                        f"row {s.dft.row} overflows max_len")
+                toks[s.dft.row, :p_i] = s.dft.pending
+                nreal[s.dft.row] = p_i
+                last[s.dft.row] = p_i - 1
+                pos[s.dft.row] = s.dft.ing
+                s.dft.pending = []
+            lg, dfeats = self.dft_dec.step_draft(
+                toks, pos, nreal, self.draft_heads["mask_embed"])
+            for s in seqs:
+                s.dft.ing += P[s.rid]
+                self.dft_dec.row_pos[s.dft.row] = s.dft.ing
+            rids, ctrs = self._by_row(self.dft_dec, seqs, lambda s: s.dft.row)
+            tok_stack, q_full, _ = DL.draft_chunk(
+                lg, dfeats, self.dp["final_norm"], self.draft_heads["heads"],
+                jnp.asarray(last), jnp.asarray(rids), jnp.asarray(ctrs),
+                self._key, g=g, dtemp=self._dt, stemp=self._st,
+                eps=self.dcfg.norm_eps, cap=self.dcfg.final_softcap,
+                mesh=self.mesh)
+            q_stack = q_full[:g]
+            # PRNG parity: token i was drawn at (rid, ctr0 + i) — the exact
+            # coordinates the sequential ticks consume.  Rows with g_i < g
+            # sampled garbage at ctr0+g_i..ctr0+g-1; those draws are discarded
+            # (glens masks them out of the verify), so the coordinate overlap
+            # with the verify window below is harmless.
+            for s in seqs:
+                s.ctr += g_of[s.rid]
+                s.stats.draft_tokens += g_of[s.rid]
         wall_draft = rec.now()
 
         # ---- verify stage: identical to the sequential round
-        pends = {s.rid: list(s.tgt.pending) for s in seqs}
-        npend = np.zeros(B, np.int32)
-        pend_arr = np.zeros((B, 2), np.int32)
-        trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB = pad lane
-        drows = np.zeros(B, np.int32)
-        rid_l = np.zeros(B, np.int32)
-        ctr_l = np.zeros(B, np.int32)
-        glens = np.zeros(B, np.int32)      # pad lanes: 0 (garbage, unread)
-        for i, s in enumerate(seqs):
-            p = pends[s.rid]
-            npend[i] = len(p)
-            pend_arr[i, :len(p)] = p
-            trows[i] = s.tgt.row
-            drows[i] = s.dft.row
-            rid_l[i] = s.rid
-            ctr_l[i] = s.ctr
-            glens[i] = g_of[s.rid]
-        Tb = DL.bucket(int((npend + glens).max()) if pred is not None
-                       else int(npend.max()) + g)
-        toks_full = DL.compose_verify_tokens(
-            jnp.asarray(pend_arr), jnp.asarray(npend), tok_stack,
-            jnp.asarray(drows), jnp.asarray(trows),
-            n_rows=self.tgt_dec.n_rows, Tb=Tb)
-        pos_t = np.minimum(self.tgt_dec.row_pos,
-                           self.tgt_dec.max_len - Tb).astype(np.int32)
-        for s in seqs:
-            self.pools["t"].extend(("t", s.rid),
-                                   len(pends[s.rid]) + g_of[s.rid])
-            if s.tgt.ing + Tb > self.tgt_dec.max_len:
-                raise RuntimeError(
-                    f"row {s.tgt.row} overflows max_len")
-            pos_t[s.tgt.row] = s.tgt.ing
-        tlg, feats = self.tgt_dec.step(toks_full, pos_t)
-        for s in seqs:
-            s.tgt.ing += len(pends[s.rid]) + g_of[s.rid]
-            self.tgt_dec.row_pos[s.tgt.row] = s.tgt.ing
-        with DL.annotate("sps_verify"):
+        with annotate("engine.verify_dispatch"):
+            pends = {s.rid: list(s.tgt.pending) for s in seqs}
+            npend = np.zeros(B, np.int32)
+            pend_arr = np.zeros((B, 2), np.int32)
+            trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB = pad lane
+            drows = np.zeros(B, np.int32)
+            rid_l = np.zeros(B, np.int32)
+            ctr_l = np.zeros(B, np.int32)
+            glens = np.zeros(B, np.int32)      # pad lanes: 0 (garbage, unread)
+            for i, s in enumerate(seqs):
+                p = pends[s.rid]
+                npend[i] = len(p)
+                pend_arr[i, :len(p)] = p
+                trows[i] = s.tgt.row
+                drows[i] = s.dft.row
+                rid_l[i] = s.rid
+                ctr_l[i] = s.ctr
+                glens[i] = g_of[s.rid]
+            Tb = DL.bucket(int((npend + glens).max()) if pred is not None
+                           else int(npend.max()) + g)
+            toks_full = DL.compose_verify_tokens(
+                jnp.asarray(pend_arr), jnp.asarray(npend), tok_stack,
+                jnp.asarray(drows), jnp.asarray(trows),
+                n_rows=self.tgt_dec.n_rows, Tb=Tb)
+            pos_t = np.minimum(self.tgt_dec.row_pos,
+                               self.tgt_dec.max_len - Tb).astype(np.int32)
+            for s in seqs:
+                self.pools["t"].extend(("t", s.rid),
+                                       len(pends[s.rid]) + g_of[s.rid])
+                if s.tgt.ing + Tb > self.tgt_dec.max_len:
+                    raise RuntimeError(
+                        f"row {s.tgt.row} overflows max_len")
+                pos_t[s.tgt.row] = s.tgt.ing
+            tlg, feats = self.tgt_dec.step(toks_full, pos_t)
+            for s in seqs:
+                s.tgt.ing += len(pends[s.rid]) + g_of[s.rid]
+                self.tgt_dec.row_pos[s.tgt.row] = s.tgt.ing
             packet_dev = DL.sps_verify(
                 tlg, q_stack, tok_stack, jnp.asarray(trows),
                 jnp.asarray(drows), jnp.asarray(npend), jnp.asarray(rid_l),
@@ -1718,63 +1746,64 @@ class BatchedSpSEngine(BatchedEngineBase):
                 g=g, ttemp=self._tt,
                 dtemp=self._dt, kernel=self._use_kernel,
                 interpret=self._kernel_interpret, mesh=self.mesh)
-        for s in seqs:
-            s.ctr += g_of[s.rid] + 1
+            for s in seqs:
+                s.ctr += g_of[s.rid] + 1
         pk = self._fetch(packet_dev)       # the round's ONLY host fetch
         wall_verify = rec.now()
         ndisp = self.dft_dec.n_calls + self.tgt_dec.n_calls - calls0
         now = self.clock + self.cost.round_cost(("serial", g, 1, ndisp))
-        committed: Dict[int, int] = {}
-        for i, s in enumerate(seqs):
-            g_i = g_of[s.rid]
-            n, nxt, all_acc = int(pk[i, 0]), int(pk[i, 1]), bool(pk[i, 2])
-            dr = [int(x) for x in pk[i, 3:3 + g_i]]
-            npend_i = len(pends[s.rid])
-            before = min(len(s.out), s.max_new)
-            s.stats.target_calls += 1
-            s.feats_last = feats[:, s.tgt.row:s.tgt.row + 1,
-                                 npend_i + g_i - 1, :]
-            s.tgt.pending = []
-            if pred is not None:
-                pred.update(s.rid, all_acc, n / max(g_i, 1))
-            if all_acc:
-                self._commit(s, dr + [nxt], now)
-                s.stats.run_extend(g_i + 1)
-                s.tgt.pending = [nxt]
-                # the chunk never entered the draft cache: re-feed it whole
-                s.dft.pending = dr + [nxt]
-                if rec.enabled:
-                    rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
-                             committed=g_i + 1, accepted=g_i, drafted=g_i,
-                             cause="accept", gamma=g_i, bonus=True,
-                             dispatches=ndisp,
-                             pred=(s.pdec.obs() if s.pdec is not None
-                                   else None), t=now)
-            else:
-                self._commit(s, dr[:n] + [nxt], now)
-                s.stats.run_extend(n)
-                s.stats.run_break()
-                s.stats.rollback_tokens += g_i - n
-                self._rollback_streams(s)
-                if rec.enabled:
-                    rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
-                             committed=n + 1, accepted=n, drafted=g_i,
-                             rolled_back=g_i - n, cause="chunk-reject",
-                             gamma=g_i, dispatches=ndisp,
-                             pred=(s.pdec.obs() if s.pdec is not None
-                                   else None), t=now)
-            committed[s.rid] = min(len(s.out), s.max_new) - before
-        if rec.enabled:
-            wall1 = rec.now()
-            rec.span("draft", wall0, wall_draft, engine=self.name)
-            rec.span("verify", wall_draft, wall_verify, engine=self.name,
-                     batch=len(seqs))
-            rec.span("commit", wall_verify, wall1, engine=self.name)
-            rec.round(engine=self.name, index=rnd_idx, mode="serial",
-                      draft_steps=g, target_calls=1, batch=len(seqs),
-                      dispatches=ndisp,
-                      wall0=wall0, wall1=wall1, t0=self.clock, t1=now)
-        self._finish_round("serial", g, 1, ndisp)
+        with annotate("engine.commit"):
+            committed: Dict[int, int] = {}
+            for i, s in enumerate(seqs):
+                g_i = g_of[s.rid]
+                n, nxt, all_acc = int(pk[i, 0]), int(pk[i, 1]), bool(pk[i, 2])
+                dr = [int(x) for x in pk[i, 3:3 + g_i]]
+                npend_i = len(pends[s.rid])
+                before = min(len(s.out), s.max_new)
+                s.stats.target_calls += 1
+                s.feats_last = feats[:, s.tgt.row:s.tgt.row + 1,
+                                     npend_i + g_i - 1, :]
+                s.tgt.pending = []
+                if pred is not None:
+                    pred.update(s.rid, all_acc, n / max(g_i, 1))
+                if all_acc:
+                    self._commit(s, dr + [nxt], now)
+                    s.stats.run_extend(g_i + 1)
+                    s.tgt.pending = [nxt]
+                    # the chunk never entered the draft cache: re-feed it whole
+                    s.dft.pending = dr + [nxt]
+                    if rec.enabled:
+                        rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
+                                 committed=g_i + 1, accepted=g_i, drafted=g_i,
+                                 cause="accept", gamma=g_i, bonus=True,
+                                 dispatches=ndisp,
+                                 pred=(s.pdec.obs() if s.pdec is not None
+                                       else None), t=now)
+                else:
+                    self._commit(s, dr[:n] + [nxt], now)
+                    s.stats.run_extend(n)
+                    s.stats.run_break()
+                    s.stats.rollback_tokens += g_i - n
+                    self._rollback_streams(s)
+                    if rec.enabled:
+                        rec.spec(rid=s.rid, round=rnd_idx, stage="sps",
+                                 committed=n + 1, accepted=n, drafted=g_i,
+                                 rolled_back=g_i - n, cause="chunk-reject",
+                                 gamma=g_i, dispatches=ndisp,
+                                 pred=(s.pdec.obs() if s.pdec is not None
+                                       else None), t=now)
+                committed[s.rid] = min(len(s.out), s.max_new) - before
+            if rec.enabled:
+                wall1 = rec.now()
+                rec.span("draft", wall0, wall_draft, engine=self.name)
+                rec.span("verify", wall_draft, wall_verify, engine=self.name,
+                         batch=len(seqs))
+                rec.span("commit", wall_verify, wall1, engine=self.name)
+                rec.round(engine=self.name, index=rnd_idx, mode="serial",
+                          draft_steps=g, target_calls=1, batch=len(seqs),
+                          dispatches=ndisp,
+                          wall0=wall0, wall1=wall1, t0=self.clock, t1=now)
+            self._finish_round("serial", g, 1, ndisp)
         return {"committed": committed, "preempted": preempted}
 
 
@@ -1856,6 +1885,7 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
             self.dft_dec.free_rows.append(st.row)
 
     # --------------------------------------------------------------- round
+    @_spanned("engine.round")
     def step_round(self) -> Dict[str, Any]:
         if self.ecfg.draft_mode == "parallel":
             # the sequential body below stays byte-identical (tests pin it
@@ -1902,7 +1932,8 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
             return (pd.would_need(d_ups) + d_extra <= pd.free_pages
                     and self.pools["t"].has_room(t_ups))
 
-        preempted = self._make_room(seqs, fits)
+        with annotate("engine.make_room"):
+            preempted = self._make_room(seqs, fits)
 
         serial = [s for s in seqs if s.mode == "draft"]
         branchers = [s for s in seqs if s.mode == "branch"]
@@ -1919,69 +1950,73 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
         pends: Dict[int, List[int]] = {}
         ks: Dict[int, int] = {}
         if branchers:
-            zero_v = jnp.zeros((self.dcfg.vocab_size,), jnp.float32)
-            qb_rows = [s.q_b for s in branchers]
-            qb_stack = jnp.stack(qb_rows
-                                 + [zero_v] * (B - len(branchers)))
-            rid_l = np.zeros(B, np.int32)
-            ctr_l = np.zeros(B, np.int32)
-            for i, s in enumerate(branchers):
-                rid_l[i] = s.rid
-                ctr_l[i] = s.ctr
-                ks[s.rid] = self._branch_k(s)
-            cands = self._fetch(DL.draw_cands(
-                qb_stack, jnp.asarray(rid_l), jnp.asarray(ctr_l),
-                self._key, K=K, stemp=self._st,
-                mode=self.ecfg.branch_mode, mesh=self.mesh))
-            if self.ecfg.branch_mode != "topk":
-                for s in branchers:
-                    s.ctr += ks[s.rid]
-            for i, s in enumerate(branchers):
-                bset = _BranchSet(cands=cands[i, :ks[s.rid]].astype(np.int64))
-                for bi in range(ks[s.rid]):
-                    row = self.dft_dec.free_rows.pop()
-                    self.dft_dec.copy_row(s.dft.row, row)
-                    self.pools["d"].fork(("d", s.rid), self._bkey(s.rid, bi))
-                    self.dft_dec.bind_row(row, self._bkey(s.rid, bi))
-                    bset.streams.append(_Stream(row=row, ing=s.dft.ing))
-                    bset.conts.append([])
-                    bset.cont_q.append([])
-                    bset.confs.append([])
-                    bset.final_sig.append(None)
-                    bset.final_conf.append(0.0)
-                bsets[s.rid] = bset
-            pends = {s.rid: list(s.tgt.pending) for s in branchers}
-            tlg, tfeats = self._ingest(
-                self.tgt_dec,
-                [(s.tgt, ("t", s.rid), s.tgt.pending + s.chunk)
-                 for s in branchers])
-            # fused chain + branch verdict (device); packet fetched after
-            # the draft phase
-            npend_l = np.zeros(B, np.int32)
-            gch_l = np.zeros(B, np.int32)
-            ks_l = np.ones(B, np.int32)
-            trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB pad
-            ctr_v = np.zeros(B, np.int32)
-            cq_rows, ct_rows = [], []
-            zero_q = jnp.zeros((CH, self.dcfg.vocab_size), jnp.float32)
-            for i, s in enumerate(branchers):
-                npend_l[i] = len(pends[s.rid])
-                gch_l[i] = len(s.chunk)
-                ks_l[i] = ks[s.rid]
-                trows[i] = s.tgt.row
-                ctr_v[i] = s.ctr
-                if s.chunk_q:
-                    cq = jnp.stack(list(s.chunk_q)
-                                   + [s.chunk_q[-1]] * (CH - len(s.chunk_q)))
-                else:
-                    cq = zero_q
-                cq_rows.append(cq)
-                ct = np.zeros(CH, np.int32)
-                ct[:len(s.chunk)] = s.chunk
-                ct_rows.append(ct)
-            cq_rows += [zero_q] * (B - len(branchers))
-            ct_rows += [np.zeros(CH, np.int32)] * (B - len(branchers))
-            with DL.annotate("branch_verify"):
+            with annotate("engine.branch_fork"):
+                zero_v = jnp.zeros((self.dcfg.vocab_size,), jnp.float32)
+                qb_rows = [s.q_b for s in branchers]
+                qb_stack = jnp.stack(qb_rows
+                                     + [zero_v] * (B - len(branchers)))
+                rid_l = np.zeros(B, np.int32)
+                ctr_l = np.zeros(B, np.int32)
+                for i, s in enumerate(branchers):
+                    rid_l[i] = s.rid
+                    ctr_l[i] = s.ctr
+                    ks[s.rid] = self._branch_k(s)
+                cands = self._fetch(DL.draw_cands(
+                    qb_stack, jnp.asarray(rid_l), jnp.asarray(ctr_l),
+                    self._key, K=K, stemp=self._st,
+                    mode=self.ecfg.branch_mode, mesh=self.mesh))
+                if self.ecfg.branch_mode != "topk":
+                    for s in branchers:
+                        s.ctr += ks[s.rid]
+                for i, s in enumerate(branchers):
+                    bset = _BranchSet(
+                        cands=cands[i, :ks[s.rid]].astype(np.int64))
+                    for bi in range(ks[s.rid]):
+                        row = self.dft_dec.free_rows.pop()
+                        self.dft_dec.copy_row(s.dft.row, row)
+                        self.pools["d"].fork(("d", s.rid),
+                                             self._bkey(s.rid, bi))
+                        self.dft_dec.bind_row(row, self._bkey(s.rid, bi))
+                        bset.streams.append(_Stream(row=row, ing=s.dft.ing))
+                        bset.conts.append([])
+                        bset.cont_q.append([])
+                        bset.confs.append([])
+                        bset.final_sig.append(None)
+                        bset.final_conf.append(0.0)
+                    bsets[s.rid] = bset
+            with annotate("engine.verify_dispatch"):
+                pends = {s.rid: list(s.tgt.pending) for s in branchers}
+                tlg, tfeats = self._ingest(
+                    self.tgt_dec,
+                    [(s.tgt, ("t", s.rid), s.tgt.pending + s.chunk)
+                     for s in branchers])
+                # fused chain + branch verdict (device); packet fetched after
+                # the draft phase
+                npend_l = np.zeros(B, np.int32)
+                gch_l = np.zeros(B, np.int32)
+                ks_l = np.ones(B, np.int32)
+                trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB pad
+                ctr_v = np.zeros(B, np.int32)
+                cq_rows, ct_rows = [], []
+                zero_q = jnp.zeros((CH, self.dcfg.vocab_size), jnp.float32)
+                for i, s in enumerate(branchers):
+                    npend_l[i] = len(pends[s.rid])
+                    gch_l[i] = len(s.chunk)
+                    ks_l[i] = ks[s.rid]
+                    trows[i] = s.tgt.row
+                    ctr_v[i] = s.ctr
+                    if s.chunk_q:
+                        cq = jnp.stack(
+                            list(s.chunk_q)
+                            + [s.chunk_q[-1]] * (CH - len(s.chunk_q)))
+                    else:
+                        cq = zero_q
+                    cq_rows.append(cq)
+                    ct = np.zeros(CH, np.int32)
+                    ct[:len(s.chunk)] = s.chunk
+                    ct_rows.append(ct)
+                cq_rows += [zero_q] * (B - len(branchers))
+                ct_rows += [np.zeros(CH, np.int32)] * (B - len(branchers))
                 packet_dev = DL.branch_verify(
                     tlg, jnp.asarray(trows), jnp.asarray(npend_l),
                     jnp.asarray(gch_l), jnp.stack(cq_rows),
@@ -1991,34 +2026,35 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
                     ttemp=self._tt, dtemp=self._dt, stemp=self._st,
                     kernel=self._use_kernel,
                     interpret=self._kernel_interpret, mesh=self.mesh)
-            for s in branchers:
-                s.ctr += self._W
+                for s in branchers:
+                    s.ctr += self._W
         wall_disp = rec.now()
 
         # ---- PHASE A: all draft-model work, interleaved batched ticks ----
-        # H-RAD prior signal decides each DRAFT-mode request's stop rule.
-        sig: Dict[int, int] = {}
-        for s in serial:
-            e_tok = s.dft.pending[0] if s.dft.pending else s.tgt.pending[0]
-            sig[s.rid] = (self._hrad_signal(s, e_tok)
-                          if self.ecfg.use_hrad else 1)
-            s.chunk, s.chunk_q = [], []
+        with annotate("engine.draft_tick"):
+            # H-RAD prior signal decides each DRAFT-mode request's stop rule.
+            sig: Dict[int, int] = {}
+            for s in serial:
+                e_tok = s.dft.pending[0] if s.dft.pending else s.tgt.pending[0]
+                sig[s.rid] = (self._hrad_signal(s, e_tok)
+                              if self.ecfg.use_hrad else 1)
+                s.chunk, s.chunk_q = [], []
 
-        # tick 0: serial rows ingest pending; branch rows ingest candidates
-        triples = []
-        for s in serial:
-            triples.append((s.dft, ("d", s.rid), list(s.dft.pending)))
-            s.dft.pending = []
-        for s in branchers:
-            bset = bsets[s.rid]
-            for i, st in enumerate(bset.streams):
-                triples.append((st, self._bkey(s.rid, i),
-                                [int(bset.cands[i])]))
-            s.stats.draft_tokens += 1      # batched candidate ingest step
-        lg, _ = self._ingest(self.dft_dec, triples)
-        last = np.zeros(n_d, np.int32)
-        for st, _, toks in triples:
-            last[st.row] = len(toks) - 1
+            # tick 0: serial rows ingest pending; branch rows ingest candidates
+            triples = []
+            for s in serial:
+                triples.append((s.dft, ("d", s.rid), list(s.dft.pending)))
+                s.dft.pending = []
+            for s in branchers:
+                bset = bsets[s.rid]
+                for i, st in enumerate(bset.streams):
+                    triples.append((st, self._bkey(s.rid, i),
+                                    [int(bset.cands[i])]))
+                s.stats.draft_tokens += 1      # batched candidate ingest step
+            lg, _ = self._ingest(self.dft_dec, triples)
+            last = np.zeros(n_d, np.int32)
+            for st, _, toks in triples:
+                last[st.row] = len(toks) - 1
         ticks = 1
 
         # Double-buffered tick pipeline (ROADMAP PR 4 remainder): tick t's
@@ -2040,6 +2076,7 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
         b_ctr0 = {s.rid: s.ctr for s in branchers}
         branch_j = {s.rid: 0 for s in branchers}
 
+        @_spanned("engine.draft_resolve")
         def resolve(p) -> None:
             """Apply one fetched tick packet: keep/stop serial chunks
             (pruning an optimistic over-ingest on epsilon stops), record
@@ -2113,48 +2150,51 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
                     pend = None
                     continue
                 break
-            rids = np.zeros(n_d, np.int32)
-            ctrs = np.zeros(n_d, np.int32)
-            srd = []
-            for s in readers:
-                i = reads[s.rid]
-                rids[s.dft.row] = s.rid
-                ctrs[s.dft.row] = ctr0[s.rid] + i
-                srd.append((s, i))
-                reads[s.rid] = i + 1
-            brd = []
-            for s in br_read:
-                j = branch_j[s.rid]
-                k_s = len(bsets[s.rid].streams)
-                for i, st in enumerate(bsets[s.rid].streams):
-                    rids[st.row] = s.rid
-                    # branch lane i draws uniform (rid, base + j*k + i):
-                    # the request consumes its OWN k per tick
-                    ctrs[st.row] = b_ctr0[s.rid] + j * k_s + i
-                brd.append((s, j))
-                branch_j[s.rid] = j + 1
-            toks_dev, qsl, packed = DL.tick_sample(
-                lg, jnp.asarray(last), jnp.asarray(rids), jnp.asarray(ctrs),
-                self._key, dtemp=self._dt, stemp=self._st, mesh=self.mesh)
+            with annotate("engine.draft_tick"):
+                rids = np.zeros(n_d, np.int32)
+                ctrs = np.zeros(n_d, np.int32)
+                srd = []
+                for s in readers:
+                    i = reads[s.rid]
+                    rids[s.dft.row] = s.rid
+                    ctrs[s.dft.row] = ctr0[s.rid] + i
+                    srd.append((s, i))
+                    reads[s.rid] = i + 1
+                brd = []
+                for s in br_read:
+                    j = branch_j[s.rid]
+                    k_s = len(bsets[s.rid].streams)
+                    for i, st in enumerate(bsets[s.rid].streams):
+                        rids[st.row] = s.rid
+                        # branch lane i draws uniform (rid, base + j*k + i):
+                        # the request consumes its OWN k per tick
+                        ctrs[st.row] = b_ctr0[s.rid] + j * k_s + i
+                    brd.append((s, j))
+                    branch_j[s.rid] = j + 1
+                toks_dev, qsl, packed = DL.tick_sample(
+                    lg, jnp.asarray(last), jnp.asarray(rids),
+                    jnp.asarray(ctrs), self._key, dtemp=self._dt,
+                    stemp=self._st, mesh=self.mesh)
             # fetch the PREVIOUS tick's packet while this tick computes
             if pend is not None:
                 resolve(pend)
             pend = (toks_dev, qsl, packed, srd, brd)
-            # optimistic ingest: every row still (believed) drafting
-            # chains its sample straight into the next forward
-            ingest_pairs = []
-            for s, i in srd:
-                if live[s.rid] and sig[s.rid] != 0 and i < g_of[s.rid]:
-                    ingest_pairs.append((s.dft, ("d", s.rid)))
-            for s, j in brd:
-                if j < gb:
-                    for i, st in enumerate(bsets[s.rid].streams):
-                        ingest_pairs.append((st, self._bkey(s.rid, i)))
-            if ingest_pairs:
-                lg, _ = self._ingest_dev(self.dft_dec, ingest_pairs,
-                                         toks_dev)
-                last[:] = 0
-                ticks += 1
+            with annotate("engine.draft_tick"):
+                # optimistic ingest: every row still (believed) drafting
+                # chains its sample straight into the next forward
+                ingest_pairs = []
+                for s, i in srd:
+                    if live[s.rid] and sig[s.rid] != 0 and i < g_of[s.rid]:
+                        ingest_pairs.append((s.dft, ("d", s.rid)))
+                for s, j in brd:
+                    if j < gb:
+                        for i, st in enumerate(bsets[s.rid].streams):
+                            ingest_pairs.append((st, self._bkey(s.rid, i)))
+                if ingest_pairs:
+                    lg, _ = self._ingest_dev(self.dft_dec, ingest_pairs,
+                                             toks_dev)
+                    last[:] = 0
+                    ticks += 1
 
         # ---- PHASE B: fetch the verdict packet, commit per brancher ----
         wall_draft1 = rec.now()
@@ -2167,30 +2207,31 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
         if branchers:
             pk = self._fetch(packet_dev)
             wall_vfetch = rec.now()
+        with annotate("engine.commit"):
             for i, s in enumerate(branchers):
                 s.tgt.pending = []
                 before = min(len(s.out), s.max_new)
                 self._branch_verdict(s, bsets[s.rid], pk[i], tfeats,
                                      len(pends[s.rid]), now)
                 committed[s.rid] = min(len(s.out), s.max_new) - before
-        for s in serial:
-            s.mode = "branch"
-        if rec.enabled:
-            wall1 = rec.now()
-            rec.span("draft", wall_disp, wall_draft1, engine=self.name,
-                     ticks=ticks)
-            if branchers:
-                # dispatched before the draft phase, fetched after it: the
-                # verify span overlapping the draft span is the paper's
-                # hidden verification, visible in Perfetto
-                rec.span("verify", wall0, wall_vfetch, engine=self.name,
-                         batch=len(branchers))
-                rec.span("commit", wall_vfetch, wall1, engine=self.name)
-            rec.round(engine=self.name, index=rnd_idx, mode=kind,
-                      draft_steps=ticks, target_calls=n_target,
-                      batch=len(seqs), wall0=wall0, wall1=wall1,
-                      t0=self.clock, t1=now)
-        self._finish_round(kind, ticks, n_target)
+            for s in serial:
+                s.mode = "branch"
+            if rec.enabled:
+                wall1 = rec.now()
+                rec.span("draft", wall_disp, wall_draft1, engine=self.name,
+                         ticks=ticks)
+                if branchers:
+                    # dispatched before the draft phase, fetched after it: the
+                    # verify span overlapping the draft span is the paper's
+                    # hidden verification, visible in Perfetto
+                    rec.span("verify", wall0, wall_vfetch, engine=self.name,
+                             batch=len(branchers))
+                    rec.span("commit", wall_vfetch, wall1, engine=self.name)
+                rec.round(engine=self.name, index=rnd_idx, mode=kind,
+                          draft_steps=ticks, target_calls=n_target,
+                          batch=len(seqs), wall0=wall0, wall1=wall1,
+                          t0=self.clock, t1=now)
+            self._finish_round(kind, ticks, n_target)
         return {"committed": committed, "preempted": preempted}
 
     def _step_round_parallel(self) -> Dict[str, Any]:
@@ -2252,7 +2293,8 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
             return (pd.would_need(d_ups) + d_extra <= pd.free_pages
                     and self.pools["t"].has_room(t_ups))
 
-        preempted = self._make_room(seqs, fits)
+        with annotate("engine.make_room"):
+            preempted = self._make_room(seqs, fits)
 
         serial = [s for s in seqs if s.mode == "draft"]
         branchers = [s for s in seqs if s.mode == "branch"]
@@ -2269,67 +2311,71 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
         pends: Dict[int, List[int]] = {}
         ks: Dict[int, int] = {}
         if branchers:
-            zero_v = jnp.zeros((self.dcfg.vocab_size,), jnp.float32)
-            qb_rows = [s.q_b for s in branchers]
-            qb_stack = jnp.stack(qb_rows
-                                 + [zero_v] * (B - len(branchers)))
-            rid_l = np.zeros(B, np.int32)
-            ctr_l = np.zeros(B, np.int32)
-            for i, s in enumerate(branchers):
-                rid_l[i] = s.rid
-                ctr_l[i] = s.ctr
-                ks[s.rid] = self._branch_k(s)
-            cands = self._fetch(DL.draw_cands(
-                qb_stack, jnp.asarray(rid_l), jnp.asarray(ctr_l),
-                self._key, K=K, stemp=self._st,
-                mode=self.ecfg.branch_mode, mesh=self.mesh))
-            if self.ecfg.branch_mode != "topk":
-                for s in branchers:
-                    s.ctr += ks[s.rid]
-            for i, s in enumerate(branchers):
-                bset = _BranchSet(cands=cands[i, :ks[s.rid]].astype(np.int64))
-                for bi in range(ks[s.rid]):
-                    row = self.dft_dec.free_rows.pop()
-                    self.dft_dec.copy_row(s.dft.row, row)
-                    self.pools["d"].fork(("d", s.rid), self._bkey(s.rid, bi))
-                    self.dft_dec.bind_row(row, self._bkey(s.rid, bi))
-                    bset.streams.append(_Stream(row=row, ing=s.dft.ing))
-                    bset.conts.append([])
-                    bset.cont_q.append([])
-                    bset.confs.append([])
-                    bset.final_sig.append(None)
-                    bset.final_conf.append(0.0)
-                bsets[s.rid] = bset
-            pends = {s.rid: list(s.tgt.pending) for s in branchers}
-            tlg, tfeats = self._ingest(
-                self.tgt_dec,
-                [(s.tgt, ("t", s.rid), s.tgt.pending + s.chunk)
-                 for s in branchers])
-            npend_l = np.zeros(B, np.int32)
-            gch_l = np.zeros(B, np.int32)
-            ks_l = np.ones(B, np.int32)
-            trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB pad
-            ctr_v = np.zeros(B, np.int32)
-            cq_rows, ct_rows = [], []
-            zero_q = jnp.zeros((CH, self.dcfg.vocab_size), jnp.float32)
-            for i, s in enumerate(branchers):
-                npend_l[i] = len(pends[s.rid])
-                gch_l[i] = len(s.chunk)
-                ks_l[i] = ks[s.rid]
-                trows[i] = s.tgt.row
-                ctr_v[i] = s.ctr
-                if s.chunk_q:
-                    cq = jnp.stack(list(s.chunk_q)
-                                   + [s.chunk_q[-1]] * (CH - len(s.chunk_q)))
-                else:
-                    cq = zero_q
-                cq_rows.append(cq)
-                ct = np.zeros(CH, np.int32)
-                ct[:len(s.chunk)] = s.chunk
-                ct_rows.append(ct)
-            cq_rows += [zero_q] * (B - len(branchers))
-            ct_rows += [np.zeros(CH, np.int32)] * (B - len(branchers))
-            with DL.annotate("branch_verify"):
+            with annotate("engine.branch_fork"):
+                zero_v = jnp.zeros((self.dcfg.vocab_size,), jnp.float32)
+                qb_rows = [s.q_b for s in branchers]
+                qb_stack = jnp.stack(qb_rows
+                                     + [zero_v] * (B - len(branchers)))
+                rid_l = np.zeros(B, np.int32)
+                ctr_l = np.zeros(B, np.int32)
+                for i, s in enumerate(branchers):
+                    rid_l[i] = s.rid
+                    ctr_l[i] = s.ctr
+                    ks[s.rid] = self._branch_k(s)
+                cands = self._fetch(DL.draw_cands(
+                    qb_stack, jnp.asarray(rid_l), jnp.asarray(ctr_l),
+                    self._key, K=K, stemp=self._st,
+                    mode=self.ecfg.branch_mode, mesh=self.mesh))
+                if self.ecfg.branch_mode != "topk":
+                    for s in branchers:
+                        s.ctr += ks[s.rid]
+                for i, s in enumerate(branchers):
+                    bset = _BranchSet(
+                        cands=cands[i, :ks[s.rid]].astype(np.int64))
+                    for bi in range(ks[s.rid]):
+                        row = self.dft_dec.free_rows.pop()
+                        self.dft_dec.copy_row(s.dft.row, row)
+                        self.pools["d"].fork(("d", s.rid),
+                                             self._bkey(s.rid, bi))
+                        self.dft_dec.bind_row(row, self._bkey(s.rid, bi))
+                        bset.streams.append(_Stream(row=row, ing=s.dft.ing))
+                        bset.conts.append([])
+                        bset.cont_q.append([])
+                        bset.confs.append([])
+                        bset.final_sig.append(None)
+                        bset.final_conf.append(0.0)
+                    bsets[s.rid] = bset
+            with annotate("engine.verify_dispatch"):
+                pends = {s.rid: list(s.tgt.pending) for s in branchers}
+                tlg, tfeats = self._ingest(
+                    self.tgt_dec,
+                    [(s.tgt, ("t", s.rid), s.tgt.pending + s.chunk)
+                     for s in branchers])
+                npend_l = np.zeros(B, np.int32)
+                gch_l = np.zeros(B, np.int32)
+                ks_l = np.ones(B, np.int32)
+                trows = np.full(B, self.tgt_dec.n_rows, np.int32)  # OOB pad
+                ctr_v = np.zeros(B, np.int32)
+                cq_rows, ct_rows = [], []
+                zero_q = jnp.zeros((CH, self.dcfg.vocab_size), jnp.float32)
+                for i, s in enumerate(branchers):
+                    npend_l[i] = len(pends[s.rid])
+                    gch_l[i] = len(s.chunk)
+                    ks_l[i] = ks[s.rid]
+                    trows[i] = s.tgt.row
+                    ctr_v[i] = s.ctr
+                    if s.chunk_q:
+                        cq = jnp.stack(
+                            list(s.chunk_q)
+                            + [s.chunk_q[-1]] * (CH - len(s.chunk_q)))
+                    else:
+                        cq = zero_q
+                    cq_rows.append(cq)
+                    ct = np.zeros(CH, np.int32)
+                    ct[:len(s.chunk)] = s.chunk
+                    ct_rows.append(ct)
+                cq_rows += [zero_q] * (B - len(branchers))
+                ct_rows += [np.zeros(CH, np.int32)] * (B - len(branchers))
                 packet_dev = DL.branch_verify(
                     tlg, jnp.asarray(trows), jnp.asarray(npend_l),
                     jnp.asarray(gch_l), jnp.stack(cq_rows),
@@ -2339,108 +2385,112 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
                     ttemp=self._tt, dtemp=self._dt, stemp=self._st,
                     kernel=self._use_kernel,
                     interpret=self._kernel_interpret, mesh=self.mesh)
-            for s in branchers:
-                s.ctr += self._W
+                for s in branchers:
+                    s.ctr += self._W
         wall_disp = rec.now()
 
         # ---- PHASE A: ONE shared draft dispatch for every row ----
-        sig: Dict[int, int] = {}
-        for s in serial:
-            e_tok = s.dft.pending[-1] if s.dft.pending else s.tgt.pending[-1]
-            sig[s.rid] = (self._hrad_signal(s, e_tok)
-                          if self.ecfg.use_hrad else 1)
-            s.chunk, s.chunk_q = [], []
+        with annotate("engine.draft_tick"):
+            sig: Dict[int, int] = {}
+            for s in serial:
+                e_tok = (s.dft.pending[-1] if s.dft.pending
+                         else s.tgt.pending[-1])
+                sig[s.rid] = (self._hrad_signal(s, e_tok)
+                              if self.ecfg.use_hrad else 1)
+                s.chunk, s.chunk_q = [], []
 
-        reals: List[Tuple[_Stream, Any, List[int]]] = []
-        for s in serial:
-            reals.append((s.dft, ("d", s.rid), list(s.dft.pending)))
-            s.dft.pending = []
-        for s in branchers:
-            bset = bsets[s.rid]
-            for i, st in enumerate(bset.streams):
-                # the chunk never entered the parent's cache: each lane
-                # ingests it plus its own candidate (win.ing then equals
-                # the committed count after an adopt — _branch_verdict and
-                # _prune_draft work unchanged)
-                reals.append((st, self._bkey(s.rid, i),
-                              list(s.chunk) + [int(bset.cands[i])]))
-            s.stats.draft_tokens += 1      # candidate ingest
-        T = DL.bucket(max(len(t) for _, _, t in reals) + G)
-        toks = np.zeros((n_d, T), np.int32)
-        nreal = np.zeros(n_d, np.int32)
-        last = np.zeros(n_d, np.int32)
-        pos = np.minimum(self.dft_dec.row_pos,
-                         self.dft_dec.max_len - T).astype(np.int32)
-        for st, key, t in reals:
-            self._pool_of(key).extend(key, len(t))
-            if st.ing + T > self.dft_dec.max_len:
-                raise RuntimeError(f"row {st.row} overflows max_len")
-            toks[st.row, :len(t)] = t
-            nreal[st.row] = len(t)
-            last[st.row] = len(t) - 1
-            pos[st.row] = st.ing
-        lg, dfeats = self.dft_dec.step_draft(
-            toks, pos, nreal, self.draft_heads["mask_embed"])
-        for st, _, t in reals:
-            st.ing += len(t)
-            self.dft_dec.row_pos[st.row] = st.ing
-        rids = np.zeros(n_d, np.int32)
-        ctrs = np.zeros(n_d, np.int32)
-        for s in serial:
-            rids[s.dft.row] = s.rid
-            ctrs[s.dft.row] = s.ctr
-        for s in branchers:
-            for i, st in enumerate(bsets[s.rid].streams):
-                rids[st.row] = s.rid
-                ctrs[st.row] = s.ctr + i * gb
-        tok_stack, q_full, packed = DL.draft_chunk(
-            lg, dfeats, self.dp["final_norm"], self.draft_heads["heads"],
-            jnp.asarray(last), jnp.asarray(rids), jnp.asarray(ctrs),
-            self._key, g=G, dtemp=self._dt, stemp=self._st,
-            eps=self.dcfg.norm_eps, cap=self.dcfg.final_softcap,
-            mesh=self.mesh)
-        pkt = self._fetch(packed)          # (n_d, G+1, 2) [token, conf]
-        ticks = 1
+            reals: List[Tuple[_Stream, Any, List[int]]] = []
+            for s in serial:
+                reals.append((s.dft, ("d", s.rid), list(s.dft.pending)))
+                s.dft.pending = []
+            for s in branchers:
+                bset = bsets[s.rid]
+                for i, st in enumerate(bset.streams):
+                    # the chunk never entered the parent's cache: each
+                    # lane ingests it plus its own candidate (win.ing then
+                    # equals the committed count after an adopt —
+                    # _branch_verdict and _prune_draft work unchanged)
+                    reals.append((st, self._bkey(s.rid, i),
+                                  list(s.chunk) + [int(bset.cands[i])]))
+                s.stats.draft_tokens += 1      # candidate ingest
+            T = DL.bucket(max(len(t) for _, _, t in reals) + G)
+            toks = np.zeros((n_d, T), np.int32)
+            nreal = np.zeros(n_d, np.int32)
+            last = np.zeros(n_d, np.int32)
+            pos = np.minimum(self.dft_dec.row_pos,
+                             self.dft_dec.max_len - T).astype(np.int32)
+            for st, key, t in reals:
+                self._pool_of(key).extend(key, len(t))
+                if st.ing + T > self.dft_dec.max_len:
+                    raise RuntimeError(f"row {st.row} overflows max_len")
+                toks[st.row, :len(t)] = t
+                nreal[st.row] = len(t)
+                last[st.row] = len(t) - 1
+                pos[st.row] = st.ing
+            lg, dfeats = self.dft_dec.step_draft(
+                toks, pos, nreal, self.draft_heads["mask_embed"])
+            for st, _, t in reals:
+                st.ing += len(t)
+                self.dft_dec.row_pos[st.row] = st.ing
+            rids = np.zeros(n_d, np.int32)
+            ctrs = np.zeros(n_d, np.int32)
+            for s in serial:
+                rids[s.dft.row] = s.rid
+                ctrs[s.dft.row] = s.ctr
+            for s in branchers:
+                for i, st in enumerate(bsets[s.rid].streams):
+                    rids[st.row] = s.rid
+                    ctrs[st.row] = s.ctr + i * gb
+            tok_stack, q_full, packed = DL.draft_chunk(
+                lg, dfeats, self.dp["final_norm"], self.draft_heads["heads"],
+                jnp.asarray(last), jnp.asarray(rids), jnp.asarray(ctrs),
+                self._key, g=G, dtemp=self._dt, stemp=self._st,
+                eps=self.dcfg.norm_eps, cap=self.dcfg.final_softcap,
+                mesh=self.mesh)
+        with annotate("engine.draft_resolve"):
+            pkt = self._fetch(packed)          # (n_d, G+1, 2) [token, conf]
+            ticks = 1
 
-        # post-hoc stop rules, serial rows: confidences for every position
-        # are on host — pick the stop point directly, no optimistic ingest
-        for s in serial:
-            row = s.dft.row
-            g_i = g_of[s.rid]
-            if sig[s.rid] == 0:
-                stop_j = 0
-            elif sig[s.rid] == 1:
-                stop_j = next((j for j in range(g_i)
-                               if float(pkt[row, j, 1]) < eps_of[s.rid]),
-                              g_i)
-            else:
-                stop_j = g_i
-            s.chunk = [int(pkt[row, j, 0]) for j in range(stop_j)]
-            s.chunk_q = [q_full[j, row] for j in range(stop_j)]
-            s.q_b = q_full[stop_j, row]
-            s.q_b_conf = float(pkt[row, stop_j, 1])
-            s.ctr += stop_j
-            s.stats.draft_tokens += stop_j + 1
-            if rec.enabled:
-                rec.spec(rid=s.rid, round=rnd_idx, stage="draft",
-                         drafted=stop_j + 1, gamma=g_i,
-                         eps_stop=(sig[s.rid] == 1 and stop_j < g_i),
-                         hrad=(sig[s.rid] if self.ecfg.use_hrad else None),
-                         pred=(s.pdec.obs() if s.pdec is not None
-                               else None),
-                         t=self.clock)
-        # branch lanes: continuation tokens/confidences off the same packet
-        for s in branchers:
-            bset = bsets[s.rid]
-            for i, st in enumerate(bset.streams):
-                row = st.row
-                bset.conts[i] = [int(pkt[row, j, 0]) for j in range(gb)]
-                bset.cont_q[i] = [q_full[j, row] for j in range(gb)]
-                bset.confs[i] = [float(pkt[row, j, 1]) for j in range(gb)]
-                bset.final_sig[i] = q_full[gb, row]
-                bset.final_conf[i] = float(pkt[row, gb, 1])
-            s.stats.draft_tokens += gb
-            s.ctr += len(bset.streams) * gb
+            # post-hoc stop rules, serial rows: confidences for every
+            # position are on host — pick the stop point directly, no
+            # optimistic ingest
+            for s in serial:
+                row = s.dft.row
+                g_i = g_of[s.rid]
+                if sig[s.rid] == 0:
+                    stop_j = 0
+                elif sig[s.rid] == 1:
+                    stop_j = next((j for j in range(g_i)
+                                   if float(pkt[row, j, 1]) < eps_of[s.rid]),
+                                  g_i)
+                else:
+                    stop_j = g_i
+                s.chunk = [int(pkt[row, j, 0]) for j in range(stop_j)]
+                s.chunk_q = [q_full[j, row] for j in range(stop_j)]
+                s.q_b = q_full[stop_j, row]
+                s.q_b_conf = float(pkt[row, stop_j, 1])
+                s.ctr += stop_j
+                s.stats.draft_tokens += stop_j + 1
+                if rec.enabled:
+                    rec.spec(rid=s.rid, round=rnd_idx, stage="draft",
+                             drafted=stop_j + 1, gamma=g_i,
+                             eps_stop=(sig[s.rid] == 1 and stop_j < g_i),
+                             hrad=(sig[s.rid] if self.ecfg.use_hrad else None),
+                             pred=(s.pdec.obs() if s.pdec is not None
+                                   else None),
+                             t=self.clock)
+            # branch lanes: continuation tokens/confidences off the same packet
+            for s in branchers:
+                bset = bsets[s.rid]
+                for i, st in enumerate(bset.streams):
+                    row = st.row
+                    bset.conts[i] = [int(pkt[row, j, 0]) for j in range(gb)]
+                    bset.cont_q[i] = [q_full[j, row] for j in range(gb)]
+                    bset.confs[i] = [float(pkt[row, j, 1]) for j in range(gb)]
+                    bset.final_sig[i] = q_full[gb, row]
+                    bset.final_conf[i] = float(pkt[row, gb, 1])
+                s.stats.draft_tokens += gb
+                s.ctr += len(bset.streams) * gb
 
         # ---- PHASE B: fetch the verdict packet, commit per brancher ----
         wall_draft1 = rec.now()
@@ -2455,28 +2505,29 @@ class BatchedSpecBranchEngine(BatchedEngineBase):
         if branchers:
             pk = self._fetch(packet_dev)
             wall_vfetch = rec.now()
+        with annotate("engine.commit"):
             for i, s in enumerate(branchers):
                 s.tgt.pending = []
                 before = min(len(s.out), s.max_new)
                 self._branch_verdict(s, bsets[s.rid], pk[i], tfeats,
                                      len(pends[s.rid]), now)
                 committed[s.rid] = min(len(s.out), s.max_new) - before
-        for s in serial:
-            s.mode = "branch"
-        if rec.enabled:
-            wall1 = rec.now()
-            rec.span("draft", wall_disp, wall_draft1, engine=self.name,
-                     ticks=ticks)
-            if branchers:
-                rec.span("verify", wall0, wall_vfetch, engine=self.name,
-                         batch=len(branchers))
-                rec.span("commit", wall_vfetch, wall1, engine=self.name)
-            rec.round(engine=self.name, index=rnd_idx, mode=kind,
-                      draft_steps=ticks, target_calls=n_target,
-                      batch=len(seqs), dispatches=ndisp,
-                      wall0=wall0, wall1=wall1,
-                      t0=self.clock, t1=now)
-        self._finish_round(kind, ticks, n_target, ndisp)
+            for s in serial:
+                s.mode = "branch"
+            if rec.enabled:
+                wall1 = rec.now()
+                rec.span("draft", wall_disp, wall_draft1, engine=self.name,
+                         ticks=ticks)
+                if branchers:
+                    rec.span("verify", wall0, wall_vfetch, engine=self.name,
+                             batch=len(branchers))
+                    rec.span("commit", wall_vfetch, wall1, engine=self.name)
+                rec.round(engine=self.name, index=rnd_idx, mode=kind,
+                          draft_steps=ticks, target_calls=n_target,
+                          batch=len(seqs), dispatches=ndisp,
+                          wall0=wall0, wall1=wall1,
+                          t0=self.clock, t1=now)
+            self._finish_round(kind, ticks, n_target, ndisp)
         return {"committed": committed, "preempted": preempted}
 
     # --------------------------------------------------- verdict (packet)

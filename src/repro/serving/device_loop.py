@@ -30,7 +30,6 @@ Design rules:
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import os
 
@@ -40,32 +39,22 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.kernels import verify_accept as _va
 from repro.models import layers as _L
+from repro.obs import trace as _obs
 from repro.runtime import sampling as S
 
 __all__ = ["bucket", "prefill_bucket", "prefill_rungs", "kernel_route",
            "tick_sample",
            "draft_chunk", "masked_token_column", "compose_verify_tokens",
            "sps_verify", "draw_cands", "branch_verify",
-           "set_trace_annotations", "annotate"]
+           "set_trace_annotations"]
 
-# jax.profiler named-range annotations around the loop's dispatch sites.
-# Off by default — ``annotate`` returns a nullcontext, so the hot path pays
-# one module-global read.  launch/serve.py turns them on with
-# ``--profile-dir`` so the device profile's ranges line up with the
-# host-side trace.json lanes (obs/export.py).
-_ANNOTATE = False
-
-
-def set_trace_annotations(on: bool) -> None:
-    global _ANNOTATE
-    _ANNOTATE = bool(on)
-
-
-def annotate(name: str):
-    """Named profiler range when annotations are on; free otherwise."""
-    if _ANNOTATE:
-        return jax.profiler.TraceAnnotation(name)
-    return contextlib.nullcontext()
+# The switch of the program's ``engine.*`` profiler spans (obs/trace.py,
+# DESIGN.md §7.9): named ranges in the profiler's own trace, on the same
+# clock as the device's operations, free while off.  Re-exported here for
+# the callers that have always turned the spans on from this module (the
+# on-chip benchmark's traced run); launch/serve.py --profile-dir turns
+# them on through obs.
+set_trace_annotations = _obs.set_trace_annotations
 
 
 def _replicated(tree, mesh):
